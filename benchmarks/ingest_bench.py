@@ -1,4 +1,4 @@
-"""Streaming GrC ingestion benchmark: build throughput + peak RSS.
+"""Streaming GrC ingestion benchmark: build throughput + peak host memory.
 
 The paper's premise (PLAR §3.3, Fig. 9) is that the granularity
 representation is small enough to cache — but *getting there* used to
@@ -6,11 +6,11 @@ require the uncompressed ``(n_rows, n_attrs)`` table resident on the host.
 This section measures what the streaming build (DESIGN.md §3.6) buys:
 
 * ``ingest_stream_vs_monolithic`` — same table, both ingestion paths, in
-  *separate subprocesses* so each run's ``ru_maxrss`` is a clean per-path
-  peak (RSS high-water marks are monotone within a process, so in-process
-  before/after deltas would be meaningless).  Streaming peak memory is
-  O(chunk + granularity capacity); monolithic is O(n_rows · n_attrs) plus
-  the sort's key copies.
+  this process (one process owns the accelerator; a child could not reach
+  it).  Each path's peak host memory is the ``tracemalloc`` high-water
+  mark of its host allocations — the numpy row chunks or the whole table —
+  reset between paths.  Streaming peaks at O(chunk + granularity
+  capacity); monolithic at O(n_rows · n_attrs).
 * ``ingest_paper_scale`` — the Table-5 flagship kdd99 at its full 5M×41
   shape, streaming only (the whole point: the monolithic path at this shape
   is exactly what we no longer need).  Granule counts are asserted equal
@@ -23,48 +23,41 @@ ingest_paper_scale --tag ingest`` when refreshing the acceptance evidence).
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
+import dataclasses
+import time
+import tracemalloc
 from typing import Dict, List
-
-_CHILD = r"""
-import dataclasses, json, resource, sys, time
-mode, name, n_rows, chunk_rows = sys.argv[1], sys.argv[2], int(sys.argv[3]), int(sys.argv[4])
-import jax.numpy as jnp
-from repro.core import build_granularity, build_granularity_streaming
-from repro.data import paper_dataset
-
-t = paper_dataset(name)
-if n_rows:
-    t = dataclasses.replace(t, n_rows=n_rows)
-t0 = time.perf_counter()
-if mode == "monolithic":
-    x, d = t.table()
-    g = build_granularity(jnp.asarray(x), jnp.asarray(d), n_dec=t.n_dec, v_max=t.v_max)
-else:
-    g = build_granularity_streaming(t.chunks(chunk_rows), n_dec=t.n_dec, v_max=t.v_max)
-out = {
-    "granules": int(g.num),
-    "elapsed_s": round(time.perf_counter() - t0, 2),
-    # linux ru_maxrss is KiB
-    "peak_rss_mb": round(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024, 1),
-}
-print(json.dumps(out))
-"""
 
 
 def _ingest(mode: str, name: str, n_rows: int, chunk_rows: int) -> Dict:
-    """Run one ingestion in a fresh python; return its self-reported stats."""
-    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    env = {**os.environ, "PYTHONPATH": src}
-    r = subprocess.run(
-        [sys.executable, "-c", _CHILD, mode, name, str(n_rows), str(chunk_rows)],
-        env=env, capture_output=True, text=True, timeout=1800)
-    if r.returncode != 0:
-        raise RuntimeError(f"ingest child failed:\n{r.stderr}")
-    return json.loads(r.stdout.strip().splitlines()[-1])
+    """One ingestion path; its granule count, wall time and host peak."""
+    import jax.numpy as jnp
+
+    from repro.core import build_granularity, build_granularity_streaming
+    from repro.data import paper_dataset
+
+    t = paper_dataset(name)
+    if n_rows:
+        t = dataclasses.replace(t, n_rows=n_rows)
+    tracemalloc.start()
+    tracemalloc.reset_peak()
+    t0 = time.perf_counter()
+    try:
+        if mode == "monolithic":
+            x, d = t.table()
+            g = build_granularity(jnp.asarray(x), jnp.asarray(d),
+                                  n_dec=t.n_dec, v_max=t.v_max)
+            del x, d
+        else:
+            g = build_granularity_streaming(t.chunks(chunk_rows),
+                                            n_dec=t.n_dec, v_max=t.v_max)
+        granules = int(g.num)
+        elapsed = time.perf_counter() - t0
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    return {"granules": granules, "elapsed_s": round(elapsed, 2),
+            "peak_host_mb": round(peak / 2**20, 1)}
 
 
 def _row(name: str, mode: str, n_rows: int, chunk_rows: int, stats: Dict) -> Dict:
@@ -75,7 +68,7 @@ def _row(name: str, mode: str, n_rows: int, chunk_rows: int, stats: Dict) -> Dic
         "chunk_rows": chunk_rows if mode == "streaming" else "-",
         "granules": stats["granules"],
         "elapsed_s": stats["elapsed_s"],
-        "peak_rss_mb": stats["peak_rss_mb"],
+        "peak_host_mb": stats["peak_host_mb"],
         "krows_per_s": round(n_rows / max(stats["elapsed_s"], 1e-9) / 1e3, 1),
     }
 
@@ -91,9 +84,10 @@ def ingest_stream_vs_monolithic() -> List[Dict]:
         rows.append(_row(name, "monolithic", n_rows, chunk_rows, mono))
         rows.append(_row(name, "streaming", n_rows, chunk_rows, stream))
         rows.append({
-            "dataset": name, "rows": n_rows, "mode": "rss_ratio",
+            "dataset": name, "rows": n_rows, "mode": "host_peak_ratio",
             "chunk_rows": "-", "granules": "-", "elapsed_s": "-",
-            "peak_rss_mb": round(mono["peak_rss_mb"] / stream["peak_rss_mb"], 2),
+            "peak_host_mb": round(mono["peak_host_mb"]
+                                  / max(stream["peak_host_mb"], 0.1), 2),
             "krows_per_s": "-",
         })
     return rows

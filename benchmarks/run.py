@@ -62,6 +62,10 @@ PRESETS = {
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
+
     from .autotune_bench import ALL_AUTOTUNE_BENCHES
     from .chaos_bench import ALL_CHAOS_BENCHES
     from .engine_bench import ALL_ENGINE_BENCHES

@@ -294,19 +294,39 @@ def shard_decision_table(x: np.ndarray, d: np.ndarray, mesh: Mesh):
     )
 
 
+def _data_shard_devices(mesh: Mesh) -> List:
+    """One device per data shard: the first device of the mesh that holds
+    row block ``s`` of an array sharded over the data axes."""
+    nd = _n_data_shards(mesh)
+    sharding = NamedSharding(mesh, P(_data_axes(mesh)))
+    devs = [None] * nd
+    for dev, idx in sharding.devices_indices_map((nd,)).items():
+        s = idx[0].start or 0
+        if devs[s] is None:
+            devs[s] = dev
+    return devs
+
+
 def _shard_granularities_to_mesh(shard_grans, mesh: Mesh):
-    """Place per-shard granule tables (equal capacities) row-sharded on the mesh."""
+    """Place per-shard granule tables (equal capacities) row-sharded on the
+    mesh: shard ``s``'s table goes to the devices that hold row block ``s``
+    (a device-to-device copy where it already lives on one of them), and
+    the global array is assembled from those pieces — no host round trip,
+    and no shard staged through one device."""
     daxes = _data_axes(mesh)
-    sh = lambda *spec: NamedSharding(mesh, P(*spec))
-    gx = jax.device_put(np.concatenate([np.asarray(g.x) for g in shard_grans]),
-                        sh(daxes, None))
-    gd = jax.device_put(np.concatenate([np.asarray(g.d) for g in shard_grans]),
-                        sh(daxes))
-    gw = jax.device_put(np.concatenate([np.asarray(g.w) for g in shard_grans]),
-                        sh(daxes))
-    gv = jax.device_put(np.concatenate([np.asarray(g.valid) for g in shard_grans]),
-                        sh(daxes))
-    return gx, gd, gw, gv
+    cap_ps = shard_grans[0].capacity
+
+    def assemble(field, spec):
+        parts = [getattr(g, field) for g in shard_grans]
+        shape = (cap_ps * len(parts),) + tuple(parts[0].shape[1:])
+        sharding = NamedSharding(mesh, P(*spec))
+        pieces = [jax.device_put(parts[(idx[0].start or 0) // cap_ps], dev)
+                  for dev, idx
+                  in sharding.addressable_devices_indices_map(shape).items()]
+        return jax.make_array_from_single_device_arrays(shape, sharding, pieces)
+
+    return (assemble("x", (daxes, None)), assemble("d", (daxes,)),
+            assemble("w", (daxes,)), assemble("valid", (daxes,)))
 
 
 def _granularity_from_source(source, mesh: Mesh, *, n_dec: int, v_max: int,
@@ -322,17 +342,22 @@ def _granularity_from_source(source, mesh: Mesh, *, n_dec: int, v_max: int,
     (for a real out-of-core reader that would multiply IO by the shard
     count).  Cross-shard duplicate keys are allowed — the contingency sum
     is key-additive (module docstring) — so shards granulate independently,
-    exactly like the per-partition combiner of a Spark reduceByKey.
+    exactly like the per-partition combiner of a Spark reduceByKey.  Each
+    shard's fold runs on a device that holds that shard.
     """
     nd = _n_data_shards(mesh)
+    devs = _data_shard_devices(mesh)
     accs = [None] * nd
     for i in range(source.n_chunks(chunk_rows)):
         xc, dc = source.chunk(i, chunk_rows)
         n = xc.shape[0]
         for s in range(nd):
             lo, hi = s * n // nd, (s + 1) * n // nd
-            accs[s] = fold_chunk(accs[s], xc[lo:hi], dc[lo:hi],
-                                 n_dec=n_dec, v_max=v_max)
+            # each shard folds on its own device (committed inputs pin the
+            # jitted build there), not on the default device
+            accs[s] = fold_chunk(
+                accs[s], jax.device_put(xc[lo:hi], devs[s]),
+                jax.device_put(dc[lo:hi], devs[s]), n_dec=n_dec, v_max=v_max)
     if any(g is None for g in accs):
         raise ValueError("source yielded no rows for at least one data shard")
     cap_ps = max(next_pow2(max(int(g.num), 16)) for g in accs)

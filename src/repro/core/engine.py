@@ -60,7 +60,6 @@ from __future__ import annotations
 
 import dataclasses
 from functools import lru_cache, partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -668,9 +667,7 @@ def run_engine(runner, cap: int, n_attrs: int, valid, x, d, w, n,
         loop_s = time.perf_counter() - t_loop
         reduct, hist, iters, n_evals = unpack_result(fin, len(forced))
         traces_after = _jit_cache_size(runner)
-        compiled = (traces_before is not None
-                    and traces_after is not None
-                    and traces_after > traces_before)
+        compiled = traces_after > traces_before
         sp.set(k=len(reduct), iterations=iters, compiled=compiled)
     obs.counter("plar_engine_runs_total",
                 "engine while_loop dispatch sequences completed").inc()
@@ -690,17 +687,10 @@ def run_engine(runner, cap: int, n_attrs: int, valid, x, d, w, n,
     return reduct, hist, iters, n_evals, [per_body] * n_bodies
 
 
-def _jit_cache_size(runner) -> Optional[int]:
-    """Traced-executable count of a jitted callable, when the running jax
-    exposes it (``_cache_size``) — lets the dispatch span tell a compile
-    from a cache hit.  None when unavailable: never guess."""
-    probe = getattr(runner, "_cache_size", None)
-    if probe is None:
-        return None
-    try:
-        return int(probe())
-    except Exception:
-        return None
+def _jit_cache_size(runner) -> int:
+    """Traced-executable count of a jitted callable — lets the dispatch
+    span tell a compile from a cache hit."""
+    return int(runner._cache_size())
 
 
 def unpack_result(fin: SelectionState, core_count: int):
@@ -1050,9 +1040,7 @@ def run_ensemble(runner, cap: int, n_attrs: int, valid, x, d,
         st = init_ensemble_state(cap, n_attrs, valid, ops.n_cfgs)
         fin = jax.block_until_ready(runner(st, x, d, ops))
         traces_after = _jit_cache_size(runner)
-        sp.set(compiled=(traces_before is not None
-                         and traces_after is not None
-                         and traces_after > traces_before))
+        sp.set(compiled=traces_after > traces_before)
     obs.counter("plar_engine_ensemble_runs_total",
                 "stacked-engine dispatches completed").inc()
     return fin, time.perf_counter() - t0

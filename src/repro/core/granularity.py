@@ -11,10 +11,12 @@ TPU/XLA adaptation (static shapes, no host round-trips):
   ``h(row) = Σ_j mix32(x[:, j] ⊕ seed_j) · m_j (mod 2³²)`` with two independent
   seeds.  Linearity lets us add/remove one column's contribution in O(1) — used
   by the attribute-core computation, where the paper re-maps from scratch.
-* "unique rows" is a lexsort + adjacent-compare + ``segment_sum`` — the
-  reduceByKey of the GrC build.  ``exact=True`` sorts the actual columns
-  (collision-free); ``exact=False`` sorts the 64-bit fingerprint pair only
-  (collision probability < G²/2⁻⁶⁴, used for very wide tables such as SDSS).
+* "unique rows" is the reduceByKey of the GrC build.  ``exact=True`` groups
+  the actual columns without a sort (:func:`exact_class_ids`: one
+  presence-bitmap compaction per column — a large multi-operand sort is
+  what a TPU compiles slowest); ``exact=False`` sorts the 64-bit fingerprint
+  pair only (collision probability < G²/2⁻⁶⁴, used for very wide tables
+  such as SDSS).  Either way a ``segment_sum`` merges the weights.
 * The output table is padded to a static capacity with a validity mask; ``num``
   carries the live granule count.
 """
@@ -22,7 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +46,8 @@ __all__ = [
     "regranulate",
     "pack_ids",
     "compact_ids",
+    "exact_class_ids",
+    "ids_by_sort",
     "project_columns",
 ]
 
@@ -137,45 +141,29 @@ class Granularity:
         return cls(*children, *aux)
 
 
-def _sort_keys(
-    x: jnp.ndarray,
-    d: jnp.ndarray,
-    valid: jnp.ndarray,
-    exact: bool,
-    seed: int,
-):
-    """Sort keys grouping equal rows; invalid rows sort to the end."""
-    sentinel = jnp.uint32(0xFFFFFFFF)
-    h1 = jnp.where(valid, row_fingerprints(x, seed), sentinel)
-    h2 = jnp.where(valid, row_fingerprints(x, seed + 7919), sentinel)
-    du = jnp.where(valid, d.astype(jnp.uint32), sentinel)
-    if exact:
-        # Primary: fingerprints (cheap bucketing); within buckets, the actual
-        # columns break hash collisions, making the grouping collision-free.
-        cols = [jnp.where(valid, x[:, j].astype(jnp.uint32), sentinel) for j in range(x.shape[1])]
-        keys = tuple(cols[::-1]) + (du, h2, h1)  # last key = primary
-    else:
-        keys = (du, h2, h1)
-    order = jnp.lexsort(keys)
-    return order, (h1, h2, du)
+def ids_by_sort(keys: Sequence[jnp.ndarray],
+                valid: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Exact dense ids for arbitrary sort keys (the reduceByKey grouping).
 
-
-def _boundaries(
-    x_s: jnp.ndarray,
-    d_s: jnp.ndarray,
-    valid_s: jnp.ndarray,
-    hashes_s: Sequence[jnp.ndarray],
-    exact: bool,
-) -> jnp.ndarray:
-    if exact:
-        neq = (x_s[1:] != x_s[:-1]).any(axis=-1) | (d_s[1:] != d_s[:-1])
-    else:
-        neq = jnp.zeros(x_s.shape[0] - 1, dtype=bool)
-        for h in hashes_s:
-            neq = neq | (h[1:] != h[:-1])
-    first = jnp.ones((1,), dtype=bool)
-    b = jnp.concatenate([first, neq])
-    return b & valid_s
+    ``keys[-1]`` is the primary sort key.  Returns ids in *original* order and
+    the number of distinct keys K.  Invalid slots get id 0 and do not count.
+    """
+    n = valid.shape[0]
+    sentineled = []
+    for k in keys:
+        ku = k.astype(jnp.uint32)
+        sentineled.append(jnp.where(valid, ku, jnp.uint32(0xFFFFFFFF)))
+    order = jnp.lexsort(tuple(sentineled))
+    valid_s = valid[order]
+    neq = jnp.zeros((n - 1,), bool)
+    for k in sentineled:
+        ks = k[order]
+        neq = neq | (ks[1:] != ks[:-1])
+    b = jnp.concatenate([jnp.ones((1,), bool), neq]) & valid_s
+    ids_sorted = jnp.cumsum(b.astype(jnp.int32)) - 1
+    ids_sorted = jnp.maximum(ids_sorted, 0)
+    ids = jnp.zeros((n,), jnp.int32).at[order].set(jnp.where(valid_s, ids_sorted, 0))
+    return ids, b.sum().astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("n_dec", "v_max", "exact", "seed", "capacity"))
@@ -205,25 +193,24 @@ def build_granularity(
         valid = jnp.ones((n,), dtype=bool)
     w = jnp.where(valid, w, 0)
 
-    order, _ = _sort_keys(x, d, valid, exact, seed)
-    x_s, d_s, w_s, valid_s = x[order], d[order], w[order], valid[order]
-    h1s = row_fingerprints(x_s, seed)
-    h2s = row_fingerprints(x_s, seed + 7919)
-    b = _boundaries(x_s, d_s, valid_s, (h1s, h2s, d_s.astype(jnp.uint32)), exact)
+    if exact:
+        # granules in lexicographic (x_0, …, x_{A-1}, d) order
+        keys = jnp.concatenate([x, d[:, None].astype(x.dtype)], axis=1)
+        ids, num = exact_class_ids(keys, valid, radix=max(v_max, n_dec))
+    else:
+        # fingerprint-pair buckets: primary h1, then h2, then d
+        ids, num = ids_by_sort(
+            [d, row_fingerprints(x, seed + 7919), row_fingerprints(x, seed)],
+            valid)
 
-    ids = jnp.cumsum(b.astype(jnp.int32)) - 1  # [-1 for leading invalid-only case]
-    ids = jnp.clip(ids, 0, cap - 1)
-    num = b.sum().astype(jnp.int32)
-
-    # Invalid (padding) rows scatter out of bounds → dropped, never clipped
-    # into the last live segment where their zero rows would overwrite its
-    # representative (they sort after every valid row, so they'd all land
-    # on id num-1 otherwise).
-    ids_w = jnp.where(valid_s, ids, cap)
-    w_g = jax.ops.segment_sum(w_s, ids_w, num_segments=cap)
+    # Invalid (padding) rows — and, when num overflows cap, the overflow
+    # ids (the caller rebuilds at a larger capacity) — scatter out of
+    # bounds and are dropped.
+    ids_w = jnp.where(valid & (ids < cap), ids, cap)
+    w_g = jax.ops.segment_sum(w, ids_w, num_segments=cap)
     # Representative rows: every row in a segment shares the key, any write wins.
-    x_g = jnp.zeros((cap, n_attrs), x.dtype).at[ids_w].set(x_s)
-    d_g = jnp.zeros((cap,), d.dtype).at[ids_w].set(d_s)
+    x_g = jnp.zeros((cap, n_attrs), x.dtype).at[ids_w].set(x)
+    d_g = jnp.zeros((cap,), d.dtype).at[ids_w].set(d)
     valid_g = jnp.arange(cap) < num
 
     return Granularity(
@@ -424,6 +411,30 @@ def ids_from_presence(presence: jnp.ndarray, p: jnp.ndarray, valid: jnp.ndarray)
     p_safe = jnp.where(valid, p, 0)
     new_ids = jnp.where(valid, rank[p_safe], 0)
     return new_ids, presence.sum()
+
+
+@partial(jax.jit, static_argnames=("radix",))
+def exact_class_ids(cols: jnp.ndarray, valid: jnp.ndarray, radix: int):
+    """Exact dense class ids of the rows of ``cols [n, k]`` (values in
+    ``[0, radix)``), without a sort.
+
+    Folds one column at a time, ``p = id·radix + v``, and renumbers ``p``
+    densely through the presence bitmap (:func:`compact_ids`).  After column
+    j the ids rank the rows' ``(col_0, …, col_j)`` prefixes lexicographically
+    — the numbering a lexsort with ``col_0`` as primary key gives — and
+    ``K ≤ n`` bounds every step's packed range by ``n·radix``.  Returns
+    ``(ids, K)``; invalid rows get id 0 and do not count.
+    """
+    n, k = cols.shape
+    n_bins = n * radix
+
+    def fold(j, carry):
+        ids, _ = carry
+        p = ids * radix + jnp.take(cols, j, axis=1).astype(jnp.int32)
+        return ids_from_presence(presence_bitmap(p, valid, n_bins), p, valid)
+
+    init = (jnp.zeros((n,), jnp.int32), jnp.any(valid).astype(jnp.int32))
+    return jax.lax.fori_loop(0, k, fold, init)
 
 
 @partial(jax.jit, static_argnames=("n_bins",))

@@ -27,40 +27,42 @@ __all__ = [
 ]
 
 
-def _row_sums(cont: jnp.ndarray) -> jnp.ndarray:
-    return cont.sum(axis=-1)
-
-
 # Every measure factors as  θ(S_i) = scale(n) · θ'(row_i)  with θ' depending
 # on the counts only.  The split is load-bearing: the fused Pallas kernel
 # (DESIGN.md §5.2) runs θ' as its epilogue with no scalar operands, and the
 # fused distributed schedule psums raw partials before scaling (linearity).
 # θ' of an all-zero row is exactly 0 for all four measures.
+#
+# The row functions reduce the decision axis with keepdims and drop it only
+# at the end: the same values either way, and with ``keepdims=True`` the
+# result stays a [..., K, 1] column — the 2-D layout a Mosaic kernel
+# epilogue needs (it cannot relayout a 1-D row vector).
 
 
-def _rows_pr(cont: jnp.ndarray) -> jnp.ndarray:
+def _rows_pr(cont: jnp.ndarray, keepdims: bool = False) -> jnp.ndarray:
     """θ'_PR = |E_i|·1[|E_i/D|=1]  (class is pure → counts toward POS)."""
-    e = _row_sums(cont)
-    pure = (cont.max(axis=-1) == e) & (e > 0)
-    return e * pure.astype(cont.dtype)
+    e = cont.sum(axis=-1, keepdims=True)
+    pure = (cont.max(axis=-1, keepdims=True) == e) & (e > 0)
+    out = e * pure.astype(cont.dtype)
+    return out if keepdims else out[..., 0]
 
 
-def _rows_sce(cont: jnp.ndarray) -> jnp.ndarray:
+def _rows_sce(cont: jnp.ndarray, keepdims: bool = False) -> jnp.ndarray:
     """θ'_SCE = Σ_j |D_ij|·log(|D_ij|/|E_i|), with 0·log0 = 0."""
-    e = _row_sums(cont)
+    e = cont.sum(axis=-1, keepdims=True)
     safe_c = jnp.where(cont > 0, cont, 1.0)
     safe_e = jnp.where(e > 0, e, 1.0)
-    logs = jnp.log(safe_c) - jnp.log(safe_e)[..., None]
-    return jnp.where(cont > 0, cont * logs, 0.0).sum(axis=-1)
+    logs = jnp.log(safe_c) - jnp.log(safe_e)
+    return jnp.where(cont > 0, cont * logs, 0.0).sum(axis=-1, keepdims=keepdims)
 
 
-def _rows_lce(cont: jnp.ndarray) -> jnp.ndarray:
+def _rows_lce(cont: jnp.ndarray, keepdims: bool = False) -> jnp.ndarray:
     """θ'_LCE = Σ_j |D_ij|·(|E_i| - |D_ij|)."""
-    e = _row_sums(cont)
-    return (cont * (e[..., None] - cont)).sum(axis=-1)
+    e = cont.sum(axis=-1, keepdims=True)
+    return (cont * (e - cont)).sum(axis=-1, keepdims=keepdims)
 
 
-def _rows_cce(cont: jnp.ndarray) -> jnp.ndarray:
+def _rows_cce(cont: jnp.ndarray, keepdims: bool = False) -> jnp.ndarray:
     """θ'_CCE = |E_i|²(|E_i|-1) - Σ_j |D_ij|²(|D_ij|-1).
 
     Follows Definition 2.9 literally: (|E|/n)·C²_|E|/C²_n = e²(e-1)/(n²(n-1))
@@ -69,10 +71,12 @@ def _rows_cce(cont: jnp.ndarray) -> jnp.ndarray:
     Def-2.9 scale so the brute-force oracle and the decomposed path agree
     bit-for-bit.)
     """
-    e = _row_sums(cont)
+    e = cont.sum(axis=-1, keepdims=True)
     pos = e * e * jnp.maximum(e - 1.0, 0.0)
-    neg = (cont * cont * jnp.maximum(cont - 1.0, 0.0)).sum(axis=-1)
-    return pos - neg
+    neg = (cont * cont * jnp.maximum(cont - 1.0, 0.0)).sum(
+        axis=-1, keepdims=True)
+    out = pos - neg
+    return out if keepdims else out[..., 0]
 
 
 RAW_ROWS: Dict[str, Callable[[jnp.ndarray], jnp.ndarray]] = {

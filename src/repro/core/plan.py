@@ -52,7 +52,12 @@ import jax
 import jax.numpy as jnp
 
 from . import measures
-from .granularity import Granularity, row_fingerprints
+from .granularity import (
+    Granularity,
+    exact_class_ids,
+    ids_by_sort,
+    row_fingerprints,
+)
 
 __all__ = [
     "ids_by_sort",
@@ -129,37 +134,17 @@ def rung_for(k: int, v_max: int, rungs: Sequence[int]) -> int:
     return next(r for r in rungs if r >= need)
 
 
-def ids_by_sort(keys: Sequence[jnp.ndarray], valid: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Exact dense ids for arbitrary sort keys (the reduceByKey grouping).
-
-    ``keys[-1]`` is the primary sort key.  Returns ids in *original* order and
-    the number of distinct keys K.  Invalid slots get id 0 and do not count.
-    """
-    n = valid.shape[0]
-    sentineled = []
-    for k in keys:
-        ku = k.astype(jnp.uint32)
-        sentineled.append(jnp.where(valid, ku, jnp.uint32(0xFFFFFFFF)))
-    order = jnp.lexsort(tuple(sentineled))
-    valid_s = valid[order]
-    neq = jnp.zeros((n - 1,), bool)
-    for k in sentineled:
-        ks = k[order]
-        neq = neq | (ks[1:] != ks[:-1])
-    b = jnp.concatenate([jnp.ones((1,), bool), neq]) & valid_s
-    ids_sorted = jnp.cumsum(b.astype(jnp.int32)) - 1
-    ids_sorted = jnp.maximum(ids_sorted, 0)
-    ids = jnp.zeros((n,), jnp.int32).at[order].set(jnp.where(valid_s, ids_sorted, 0))
-    return ids, b.sum().astype(jnp.int32)
-
-
 def subset_ids(gran: Granularity, cols: jnp.ndarray, *, exact: bool, seed: int = 0):
-    """Class ids of ``U/B`` for the column subset B (dynamic index array)."""
+    """Class ids of ``U/B`` for the column subset B (dynamic index array).
+
+    ``exact`` ids are sort-free (:func:`~repro.core.granularity.
+    exact_class_ids`) and number the classes as a lexsort on B's columns
+    would; otherwise the classes are those of B's fingerprint pair.
+    """
     x_sub = gran.x[:, cols]
     if exact:
-        keys = [x_sub[:, j] for j in range(x_sub.shape[1])][::-1]
-    else:
-        keys = [row_fingerprints(x_sub, seed + 7919), row_fingerprints(x_sub, seed)]
+        return exact_class_ids(x_sub, gran.valid, radix=gran.v_max)
+    keys = [row_fingerprints(x_sub, seed + 7919), row_fingerprints(x_sub, seed)]
     return ids_by_sort(keys, gran.valid)
 
 
@@ -196,17 +181,14 @@ def _cont_onehot(packed, d, w, valid, n_bins, m, *, bin_chunk: int = 512):
     return cont[:, :n_bins, :]
 
 
-def _cont_pallas(packed, d, w, valid, n_bins, m, *, interpret: bool,
-                 selector=None):
+def _cont_pallas(packed, d, w, valid, n_bins, m, *, selector=None):
     from repro.kernels.contingency.ops import contingency as _kernel
 
     w_ = jnp.where(valid, w, 0).astype(jnp.float32)
-    return _kernel(packed, d, w_, n_bins=n_bins, n_dec=m, interpret=interpret,
-                   selector=selector)
+    return _kernel(packed, d, w_, n_bins=n_bins, n_dec=m, selector=selector)
 
 
-@partial(jax.jit, static_argnames=("n_bins", "m", "backend", "interpret",
-                                   "selector"))
+@partial(jax.jit, static_argnames=("n_bins", "m", "backend", "selector"))
 def candidate_contingency(
     packed: jnp.ndarray,
     d: jnp.ndarray,
@@ -216,7 +198,6 @@ def candidate_contingency(
     n_bins: int,
     m: int,
     backend: str = "segment",
-    interpret: bool = True,
     selector: Optional[str] = None,
 ) -> jnp.ndarray:
     """counts[c, k, j] = Σ_g w_g · 1[packed[c,g] = k] · 1[d_g = j].
@@ -231,7 +212,7 @@ def candidate_contingency(
         return _cont_onehot(packed, d, w, valid, n_bins, m)
     if backend == "pallas":
         return _cont_pallas(packed, d, w, valid, n_bins, m,
-                            interpret=interpret, selector=selector)
+                            selector=selector)
     raise ValueError(f"unknown contingency backend: {backend}")
 
 
@@ -345,7 +326,7 @@ SWEEP_BACKENDS = ("sweep", "sweep_xla")
 
 
 @partial(jax.jit, static_argnames=("delta", "n_bins", "m", "backend",
-                                   "interpret", "v_max", "selector"))
+                                   "v_max", "selector"))
 def candidate_theta(
     delta: str,
     packed: jnp.ndarray,
@@ -357,7 +338,6 @@ def candidate_theta(
     n_bins: int,
     m: int,
     backend: str = "segment",
-    interpret: bool = True,
     x_t: Optional[jnp.ndarray] = None,
     r_ids: Optional[jnp.ndarray] = None,
     v_max: Optional[int] = None,
@@ -387,8 +367,7 @@ def candidate_theta(
             w_ = jnp.where(valid, w, 0).astype(jnp.float32)
             return sweep_theta(
                 x_t, r_ids, d, w_, n, delta=delta, v_max=v_max,
-                n_bins=n_bins, n_dec=m, interpret=interpret,
-                selector=selector)
+                n_bins=n_bins, n_dec=m, selector=selector)
         return _theta_sweep_xla(
             delta, x_t, r_ids, d, w, valid, n, v_max=v_max, n_bins=n_bins,
             m=m)
@@ -398,7 +377,7 @@ def candidate_theta(
         w_ = jnp.where(valid, w, 0).astype(jnp.float32)
         return fused_theta(
             packed, d, w_, n, delta=delta, n_bins=n_bins, n_dec=m,
-            interpret=interpret, selector=selector)
+            selector=selector)
     if backend == "fused_xla":
         return _theta_fused_xla(delta, packed, d, w, valid, n, n_bins=n_bins, m=m)
     if backend not in ("segment", "onehot", "pallas"):
@@ -408,7 +387,7 @@ def candidate_theta(
             "sweep_xla)")
     cont = candidate_contingency(
         packed, d, w, valid, n_bins=n_bins, m=m, backend=backend,
-        interpret=interpret, selector=selector)
+        selector=selector)
     return measures.evaluate(delta, cont, n)
 
 

@@ -18,31 +18,19 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _ACTIVE_MESH: ContextVar[Optional[Mesh]] = ContextVar("repro_active_mesh", default=None)
 
 
-# --- jax version compatibility (DESIGN.md §2) --------------------------------
-# `jax.shard_map` / `jax.sharding.AxisType` graduated from experimental after
-# 0.4.x; these two shims are the single place the repo adapts, so every call
-# site reads identically on old and new jax.
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` where available, else experimental (check_rep API)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """``jax.shard_map`` with the repo's default ``check_vma=False``."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma)
+        check_vma=check_vma)
 
 
 def make_mesh(shape, axis_names, *, devices=None):
-    """``jax.make_mesh`` with explicit Auto axis types where supported."""
+    """``jax.make_mesh`` with explicit Auto axis types."""
     kw = {} if devices is None else {"devices": devices}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(shape, axis_names, **kw)
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kw)
 
 # Canonical logical axes (DESIGN.md §3.3):
 #   batch  → ('pod', 'data')   data parallelism (pods are pure DP)

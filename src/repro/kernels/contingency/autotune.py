@@ -8,8 +8,8 @@ Three selector modes, shared by every kernel entry point
   and picks the best modeled time.  Free (no compiles), shape-exact, and
   consistent across processes.  Tuned picks persisted by
   :func:`autotune_block_sizes` (keyed by platform × kernel × shape bucket)
-  override the model when present, so a service process reuses tunings
-  measured elsewhere.
+  override the model only when ``REPRO_AUTOTUNE_CACHE`` names the file that
+  holds them; without it, tiles are a pure function of the shape.
 * ``heuristic`` — the PR-1 zero-cost shape rule (:func:`select_block_sizes`):
   largest MXU-aligned tile under the VMEM budget.  Kept as the legacy
   fallback and as a parity baseline for the selector tests.
@@ -18,9 +18,10 @@ Three selector modes, shared by every kernel entry point
 
 :func:`autotune_block_sizes` is the measured refinement: the analytic rank
 prunes the candidate grid to a top-k (default 3) **before any timing**, and
-timing itself is opt-in (``refine=True``) — on this interpret-mode host
-timings are meaningless, and on real hardware each timed candidate costs a
-compile.  Winners land in a bounded in-memory LRU *and* the on-disk cache.
+timing itself is opt-in (``refine=True``) — in interpret mode timings are
+meaningless, and on a TPU each timed candidate costs a compile.  Winners
+land in a bounded in-memory LRU, and in the on-disk cache when
+``REPRO_AUTOTUNE_CACHE`` names one.
 """
 from __future__ import annotations
 
@@ -65,7 +66,8 @@ _CACHE_MAXSIZE = 256
 _CACHE: "OrderedDict[Tuple, Tuple[int, ...]]" = OrderedDict()
 _CACHE_STATS = {"hits": 0, "misses": 0}
 
-# On-disk tuning cache (shared across processes — the PR 5/6 service seam).
+# On-disk tuning cache, shared across processes — only at the file that
+# ``REPRO_AUTOTUNE_CACHE`` names; there is no default location.
 _DISK_ENV = "REPRO_AUTOTUNE_CACHE"
 _disk_state: Dict[str, object] = {"path": None, "data": None}
 
@@ -151,7 +153,7 @@ def resolve_tiles(
                   tiles=repr(tiles))
         return tiles
     # analytic: a persisted tuning for this (platform, kernel, shape bucket)
-    # wins over the model — measured beats modeled when available.
+    # wins over the model — measured beats modeled when a cache is named.
     tuned = _disk_get(_disk_key(jax.default_backend(), kernel,
                                 shape_bucket(nc, g, n_bins, m)))
     if tuned is not None:
@@ -188,13 +190,9 @@ def shape_bucket(nc: int, g: int, n_bins: int, m: int) -> Tuple[int, int, int, i
     return (p2(nc), p2(g), p2(n_bins), p2(m))
 
 
-def _disk_path() -> Path:
+def _disk_path() -> Optional[Path]:
     env = os.environ.get(_DISK_ENV)
-    if env:
-        return Path(env)
-    base = Path(os.environ.get("XDG_CACHE_HOME",
-                               Path.home() / ".cache")) / "repro-plar"
-    return base / "autotune.json"
+    return Path(env) if env else None
 
 
 def _disk_key(platform: str, kernel: str, bucket: Tuple[int, ...]) -> str:
@@ -208,11 +206,12 @@ def _disk_data() -> Dict[str, list]:
     if _disk_state["path"] != path or _disk_state["data"] is None:
         data: Dict[str, list] = {}
         try:
-            with open(path) as f:
-                raw = json.load(f)
-            if isinstance(raw, dict):
-                data = {str(k): list(v) for k, v in raw.items()
-                        if isinstance(v, (list, tuple))}
+            if path is not None:
+                with open(path) as f:
+                    raw = json.load(f)
+                if isinstance(raw, dict):
+                    data = {str(k): list(v) for k, v in raw.items()
+                            if isinstance(v, (list, tuple))}
         except (OSError, ValueError):
             data = {}
         _disk_state["path"] = path
@@ -226,9 +225,11 @@ def _disk_get(key: str) -> Optional[Tuple[int, ...]]:
 
 
 def _disk_put(key: str, tiles: Sequence[int]) -> None:
+    path = _disk_path()
+    if path is None:
+        return
     data = _disk_data()
     data[key] = [int(t) for t in tiles]
-    path = _disk_path()
     try:
         path.parent.mkdir(parents=True, exist_ok=True)
         tmp = path.with_suffix(".tmp")
@@ -257,28 +258,30 @@ def autotune_cache_clear(disk: bool = False) -> None:
     _CACHE.clear()
     _CACHE_STATS["hits"] = 0
     _CACHE_STATS["misses"] = 0
-    if disk:
+    path = _disk_path()
+    if disk and path is not None:
         _disk_state["data"] = {}
         try:
-            _disk_path().unlink(missing_ok=True)
+            path.unlink(missing_ok=True)
         except OSError:
             pass
 
 
 def autotune_cache_info() -> Dict[str, object]:
     """Cache observability: sizes, hit/miss counters, disk location."""
+    path = _disk_path()
     return {
         "size": len(_CACHE),
         "maxsize": _CACHE_MAXSIZE,
         "hits": _CACHE_STATS["hits"],
         "misses": _CACHE_STATS["misses"],
-        "disk_path": str(_disk_path()),
+        "disk_path": None if path is None else str(path),
         "disk_entries": len(_disk_data()),
     }
 
 
 def _build_candidate_fn(kernel, tiles, packed, wd, x_t, r_ids, *, n_bins,
-                        delta, v_max, interpret):
+                        delta, v_max):
     """Zero-arg launcher for one candidate tiling (monkeypatch seam for the
     compile-count tests)."""
     if kernel == "contingency":
@@ -286,20 +289,19 @@ def _build_candidate_fn(kernel, tiles, packed, wd, x_t, r_ids, *, n_bins,
 
         bk, bg = tiles
         return lambda: contingency_pallas(
-            packed, wd, n_bins=n_bins, bk=bk, bg=bg, interpret=interpret)
+            packed, wd, n_bins=n_bins, bk=bk, bg=bg)
     if kernel == "fused":
         from .fused import fused_theta_pallas
 
         bk, bg = tiles
         return lambda: fused_theta_pallas(
-            packed, wd, n_bins=n_bins, delta=delta, bk=bk, bg=bg,
-            interpret=interpret)
+            packed, wd, n_bins=n_bins, delta=delta, bk=bk, bg=bg)
     from .sweep import sweep_theta_pallas
 
     bc, bk, bg = tiles
     return lambda: sweep_theta_pallas(
         x_t, r_ids, wd, v_max=v_max, n_bins=n_bins, delta=delta, bc=bc,
-        bk=bk, bg=bg, interpret=interpret)
+        bk=bk, bg=bg)
 
 
 def _time_candidate(fn, reps: int) -> float:
@@ -323,7 +325,6 @@ def autotune_block_sizes(
     kernel: Optional[str] = None,
     v_max: int = 1,
     reps: int = 3,
-    interpret: bool = True,
     candidates: Optional[Sequence[Sequence[int]]] = None,
     refine: bool = False,
     top_k: int = 3,
@@ -341,7 +342,8 @@ def autotune_block_sizes(
     whose compile fails are skipped with a logged warning, never silently.
     Winners are memoized in the bounded in-memory LRU (keyed *including the
     JAX platform* — a CPU tuning must not leak onto TPU) and persisted to the
-    on-disk cache so other processes' ``analytic`` selector reuses them.
+    on-disk cache that ``REPRO_AUTOTUNE_CACHE`` names, if any, so other
+    processes' ``analytic`` selector reuses them.
     """
     if kernel is None:
         kernel = "contingency" if delta is None else "fused"
@@ -353,7 +355,7 @@ def autotune_block_sizes(
         platform = jax.default_backend()
     if candidates is not None:
         candidates = tuple(tuple(int(t) for t in c) for c in candidates)
-    key = (platform, kernel, nc, g, n_bins, m, delta, v_max, interpret, reps,
+    key = (platform, kernel, nc, g, n_bins, m, delta, v_max, reps,
            candidates, refine, top_k)
     if key in _CACHE:
         _CACHE_STATS["hits"] += 1
@@ -385,7 +387,7 @@ def autotune_block_sizes(
         for tiles, _cost, _t in ranked[:top_k]:
             fn = _build_candidate_fn(
                 kernel, tiles, packed, wd, x_t, r_ids, n_bins=n_bins,
-                delta=delta_eff, v_max=v_max, interpret=interpret)
+                delta=delta_eff, v_max=v_max)
             try:
                 jax.block_until_ready(fn())            # compile + warm
             except Exception as e:

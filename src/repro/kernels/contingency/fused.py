@@ -32,6 +32,7 @@ and removes the ``[nc, K, M]`` HBM round-trip from the hot path.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,8 +43,11 @@ from jax.experimental.pallas import tpu as pltpu
 # of truth: plain branch-free jnp, so they trace inside the kernel unchanged.
 from repro.core.measures import RAW_ROWS as EPILOGUES
 
+from repro.kernels.platform import interpret_mode
+
 from . import model
 from .kernel import DEFAULT_BG, DEFAULT_BK, _cost_estimate
+from .model import LANE
 
 
 def _fused_kernel(packed_ref, wd_ref, out_ref, acc_ref, *, bk: int, delta: str):
@@ -52,9 +56,9 @@ def _fused_kernel(packed_ref, wd_ref, out_ref, acc_ref, *, bk: int, delta: str):
     pid_g = pl.program_id(2)
     n_g = pl.num_programs(2)
 
-    p = packed_ref[0, :]                                    # [BG] int32
-    bins = pid_k * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, p.shape[0]), 0)
-    onehot = (p[None, :] == bins).astype(jnp.float32)       # [BK, BG]
+    p = packed_ref[...]                                     # [1, BG] int32
+    bins = pid_k * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, p.shape[1]), 0)
+    onehot = (p == bins).astype(jnp.float32)                # [BK, BG]
     acc = jnp.dot(onehot, wd_ref[...], preferred_element_type=jnp.float32)  # [BK, M]
 
     @pl.when(pid_g == 0)
@@ -67,15 +71,19 @@ def _fused_kernel(packed_ref, wd_ref, out_ref, acc_ref, *, bk: int, delta: str):
 
     @pl.when(pid_g == n_g - 1)
     def _epilogue():
-        partial = EPILOGUES[delta](acc_ref[...]).sum()      # scalar Θ partial
+        # The scalar Θ partial is stored broadcast over a [1, 128] lane row:
+        # Mosaic stores vectors, never scalars, into VMEM.
+        partial = jnp.broadcast_to(
+            EPILOGUES[delta](acc_ref[...], keepdims=True).sum(
+                axis=0, keepdims=True), out_ref.shape)
 
         @pl.when(pid_k == 0)
         def _first_tile():
-            out_ref[0, 0] = partial
+            out_ref[...] = partial
 
         @pl.when(pid_k != 0)
         def _later_tiles():
-            out_ref[0, 0] += partial
+            out_ref[...] += partial
 
 
 @functools.partial(
@@ -90,11 +98,12 @@ def fused_theta_pallas(
     delta: str,
     bk: int = DEFAULT_BK,
     bg: int = DEFAULT_BG,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Unnormalized Θ partials [nc]; see module docstring for the epilogue math.
 
     The caller applies the measure's sign/|U| normalization (``ops.fused_theta``).
+    ``interpret=None`` compiles on a TPU and interprets elsewhere.
     """
     if delta not in EPILOGUES:
         raise ValueError(f"unknown measure: {delta}")
@@ -115,14 +124,14 @@ def fused_theta_pallas(
         functools.partial(_fused_kernel, bk=bk, delta=delta),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bg), lambda c, k, g_: (c, g_)),
+            pl.BlockSpec((None, 1, bg), lambda c, k, g_: (c, 0, g_)),
             pl.BlockSpec((bg, m), lambda c, k, g_: (g_, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda c, k, g_: (c, 0)),
-        out_shape=jax.ShapeDtypeStruct((nc, 1), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, LANE), lambda c, k, g_: (c, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nc, 1, LANE), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bk, m), jnp.float32)],
         cost_estimate=_cost_estimate(
             model.fused_cost(nc, g, n_bins, m, bk, bg, delta=delta)),
-        interpret=interpret,
-    )(packed, wd)
-    return out[:, 0]
+        interpret=interpret_mode(interpret),
+    )(packed.reshape(nc, 1, g_pad), wd)
+    return out[:, 0, 0]

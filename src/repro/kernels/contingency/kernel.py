@@ -14,7 +14,9 @@ so the one-hot-matmul formulation *is* the hardware adaptation (DESIGN.md §2).
 
 Tiling (VMEM working set, per grid step):
 
-    packed tile  [1, BG]           int32     (4·BG bytes)
+    packed tile  [1, BG]           int32     (4·BG bytes; ``packed`` is viewed
+                                              as [nc, 1, G] so the block's last
+                                              two dims are Mosaic-legal)
     wd tile      [BG, M]           float32   (4·BG·M)
     out tile     [1, BK, M]        float32   (4·BK·M, resident across the
                                               G-axis grid walk)
@@ -29,10 +31,13 @@ double-buffering of the streamed ``wd`` tiles.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import interpret_mode
 
 from . import model
 
@@ -55,9 +60,9 @@ def _contingency_kernel(packed_ref, wd_ref, out_ref, *, bk: int):
     pid_k = pl.program_id(1)
     pid_g = pl.program_id(2)
 
-    p = packed_ref[0, :]                                   # [BG] int32
-    bins = pid_k * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, p.shape[0]), 0)
-    onehot = (p[None, :] == bins).astype(jnp.float32)       # [BK, BG]
+    p = packed_ref[...]                                    # [1, BG] int32
+    bins = pid_k * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, p.shape[1]), 0)
+    onehot = (p == bins).astype(jnp.float32)                # [BK, BG]
     acc = jnp.dot(onehot, wd_ref[...], preferred_element_type=jnp.float32)  # [BK, M]
 
     @pl.when(pid_g == 0)
@@ -80,9 +85,13 @@ def contingency_pallas(
     n_bins: int,
     bk: int = DEFAULT_BK,
     bg: int = DEFAULT_BG,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """counts[c, k, m] for compact integer keys; see module docstring."""
+    """counts[c, k, m] for compact integer keys; see module docstring.
+
+    ``interpret=None`` compiles on a TPU and interprets elsewhere
+    (:func:`repro.kernels.platform.interpret_mode`).
+    """
     nc, g = packed.shape
     m = wd.shape[1]
 
@@ -100,13 +109,13 @@ def contingency_pallas(
         functools.partial(_contingency_kernel, bk=bk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bg), lambda c, k, g_: (c, g_)),
+            pl.BlockSpec((None, 1, bg), lambda c, k, g_: (c, 0, g_)),
             pl.BlockSpec((bg, m), lambda c, k, g_: (g_, 0)),
         ],
         out_specs=pl.BlockSpec((1, bk, m), lambda c, k, g_: (c, k, 0)),
         out_shape=jax.ShapeDtypeStruct((nc, k_pad, m), jnp.float32),
         cost_estimate=_cost_estimate(
             model.contingency_cost(nc, g, n_bins, m, bk, bg)),
-        interpret=interpret,
-    )(packed, wd)
+        interpret=interpret_mode(interpret),
+    )(packed.reshape(nc, 1, g_pad), wd)
     return out[:, :n_bins, :]

@@ -76,7 +76,9 @@ GRID_STEP_OVERHEAD_S = 1e-7
 # cost nothing to consider).
 CANDIDATE_BK = (8, 16, 32, 64, 128, 256, 512)
 CANDIDATE_BG = (128, 256, 512, 1024)
-CANDIDATE_BC = (1, 2, 4, 8, 16)
+# BC is the sublane axis of the sweep kernel's candidate block, so Mosaic
+# takes only multiples of 8 (padded candidate rows are sliced off).
+CANDIDATE_BC = (8, 16)
 
 _EPILOGUE_FLOPS_PER_CELL = 8   # θ' row math: where/log/mul/add per count cell
 
@@ -218,7 +220,8 @@ def feasible_tiles(kernel: str, nc: int, g: int, n_bins: int, m: int,
 
     BK stays sublane-aligned and never exceeds the padded bin count by more
     than one tile (no all-padding bin tiles); BG is lane-aligned and capped
-    one tile above the granule count; sweep BC is capped one block above nc.
+    one tile above the granule count; sweep BC is a sublane multiple capped
+    one block above nc.
     """
     k_cap = _round_up(max(n_bins, 1), SUBLANE)
     g_cap = _round_up(max(g, 1), LANE)
@@ -226,7 +229,7 @@ def feasible_tiles(kernel: str, nc: int, g: int, n_bins: int, m: int,
     bgs = [bg for bg in CANDIDATE_BG if bg // 2 < g_cap] or [LANE]
     out = []
     if kernel == "sweep":
-        bcs = [bc for bc in CANDIDATE_BC if bc // 2 < max(nc, 1)] or [1]
+        bcs = [bc for bc in CANDIDATE_BC if bc // 2 < max(nc, 1)] or [SUBLANE]
         for bc in bcs:
             for bk in bks:
                 for bg in bgs:
@@ -239,7 +242,7 @@ def feasible_tiles(kernel: str, nc: int, g: int, n_bins: int, m: int,
                     out.append((bk, bg))
     # Never empty: the smallest aligned tile is the floor.
     if not out:
-        out = [(1, SUBLANE, LANE)] if kernel == "sweep" else [(SUBLANE, LANE)]
+        out = [(SUBLANE, SUBLANE, LANE)] if kernel == "sweep" else [(SUBLANE, LANE)]
     return tuple(out)
 
 

@@ -2,7 +2,8 @@
 
 Handles the TPU lane-width padding of the decision axis (M → multiple of 128)
 and unpadding of the result; callers see the logical ``[nc, n_bins, n_dec]``
-(unfused) or ``[nc]`` (fused Θ).
+(unfused) or ``[nc]`` (fused Θ).  The kernels compile on a TPU and run in
+the Pallas interpreter elsewhere (:mod:`repro.kernels.platform`).
 
 Tile resolution happens *here*, in plain Python, before the jitted inner
 calls: passing ``bk=None``/``bg=None`` (and ``bc=None`` for the sweep) routes
@@ -53,7 +54,6 @@ def contingency(
     n_dec: int,
     bk: Optional[int] = None,
     bg: Optional[int] = None,
-    interpret: bool = True,
     selector: Optional[str] = None,
 ) -> jnp.ndarray:
     """counts[c, k, j] = Σ_g w_g · 1[packed[c,g]=k] · 1[d_g=j]."""
@@ -65,14 +65,13 @@ def contingency(
         bk = rk if bk is None else bk
         bg = rg if bg is None else bg
     return _contingency_jit(packed, d, w, n_bins=n_bins, n_dec=n_dec,
-                            bk=bk, bg=bg, interpret=interpret)
+                            bk=bk, bg=bg)
 
 
-@partial(jax.jit, static_argnames=("n_bins", "n_dec", "bk", "bg", "interpret"))
-def _contingency_jit(packed, d, w, *, n_bins, n_dec, bk, bg, interpret):
+@partial(jax.jit, static_argnames=("n_bins", "n_dec", "bk", "bg"))
+def _contingency_jit(packed, d, w, *, n_bins, n_dec, bk, bg):
     wd, _ = _lane_padded_wd(w, d, n_dec)
-    out = contingency_pallas(packed, wd, n_bins=n_bins, bk=bk, bg=bg,
-                             interpret=interpret)
+    out = contingency_pallas(packed, wd, n_bins=n_bins, bk=bk, bg=bg)
     return out[:, :, :n_dec]
 
 
@@ -87,7 +86,6 @@ def fused_theta(
     n_dec: int,
     bk: Optional[int] = None,
     bg: Optional[int] = None,
-    interpret: bool = True,
     selector: Optional[str] = None,
 ) -> jnp.ndarray:
     """Θ(D|B∪{a})[c] without materializing the [nc, K, M] contingency tensor.
@@ -104,17 +102,14 @@ def fused_theta(
         bk = rk if bk is None else bk
         bg = rg if bg is None else bg
     return _fused_theta_jit(packed, d, w, n, delta=delta, n_bins=n_bins,
-                            n_dec=n_dec, bk=bk, bg=bg, interpret=interpret)
+                            n_dec=n_dec, bk=bk, bg=bg)
 
 
-@partial(jax.jit, static_argnames=("delta", "n_bins", "n_dec", "bk", "bg",
-                                   "interpret"))
-def _fused_theta_jit(packed, d, w, n, *, delta, n_bins, n_dec, bk, bg,
-                     interpret):
+@partial(jax.jit, static_argnames=("delta", "n_bins", "n_dec", "bk", "bg"))
+def _fused_theta_jit(packed, d, w, n, *, delta, n_bins, n_dec, bk, bg):
     wd, _ = _lane_padded_wd(w, d, n_dec)
     raw = fused_theta_pallas(
-        packed, wd, n_bins=n_bins, delta=delta, bk=bk, bg=bg,
-        interpret=interpret)
+        packed, wd, n_bins=n_bins, delta=delta, bk=bk, bg=bg)
     return theta_scale(delta, raw, n)
 
 
@@ -132,7 +127,6 @@ def sweep_theta(
     bc: Optional[int] = None,
     bk: Optional[int] = None,
     bg: Optional[int] = None,
-    interpret: bool = True,
     selector: Optional[str] = None,
 ) -> jnp.ndarray:
     """Θ(D|R∪{a})[c] from the read-once slab operands (DESIGN.md §5.3).
@@ -153,16 +147,15 @@ def sweep_theta(
         bk = rk if bk is None else bk
         bg = rg if bg is None else bg
     return _sweep_theta_jit(x_t, r_ids, d, w, n, delta=delta, v_max=v_max,
-                            n_bins=n_bins, n_dec=n_dec, bc=bc, bk=bk, bg=bg,
-                            interpret=interpret)
+                            n_bins=n_bins, n_dec=n_dec, bc=bc, bk=bk, bg=bg)
 
 
 @partial(jax.jit, static_argnames=("delta", "v_max", "n_bins", "n_dec", "bc",
-                                   "bk", "bg", "interpret"))
+                                   "bk", "bg"))
 def _sweep_theta_jit(x_t, r_ids, d, w, n, *, delta, v_max, n_bins, n_dec,
-                     bc, bk, bg, interpret):
+                     bc, bk, bg):
     wd, _ = _lane_padded_wd(w, d, n_dec)
     raw = sweep_theta_pallas(
         x_t, r_ids, wd, v_max=v_max, n_bins=n_bins, delta=delta, bc=bc,
-        bk=bk, bg=bg, interpret=interpret)
+        bk=bk, bg=bg)
     return theta_scale(delta, raw, n)

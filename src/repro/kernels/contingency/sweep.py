@@ -33,6 +33,7 @@ BK = 128, M = 128 defaults, 1 MB at BK = 256.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -41,8 +42,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.measures import RAW_ROWS as EPILOGUES
 
+from repro.kernels.platform import interpret_mode
+
 from . import model
 from .kernel import _cost_estimate
+from .model import LANE
 
 DEFAULT_BC = 8     # candidate block (shared-operand reuse factor)
 DEFAULT_BK = 128   # bin-tile (MXU sublane-aligned output rows)
@@ -56,16 +60,16 @@ def _sweep_kernel(xt_ref, r_ref, wd_ref, out_ref, acc_ref, *, bc: int,
     pid_g = pl.program_id(2)
     n_g = pl.num_programs(2)
 
-    r = r_ref[0, :]                                         # [BG] int32
+    r = r_ref[...]                                          # [1, BG] int32
     wd = wd_ref[...]                                        # [BG, M] f32
     bins = pid_k * bk + jax.lax.broadcasted_iota(
-        jnp.int32, (bk, r.shape[0]), 0)
+        jnp.int32, (bk, r.shape[1]), 0)
 
     # The candidate loop is a static unroll: r/wd stay resident in VMEM and
     # are reused by every candidate of the block (the read-once property).
     for c in range(bc):
-        p = r * v_max + xt_ref[c, :]                        # in-register pack
-        onehot = (p[None, :] == bins).astype(jnp.float32)   # [BK, BG]
+        p = r * v_max + xt_ref[pl.ds(c, 1), :]              # in-register pack
+        onehot = (p == bins).astype(jnp.float32)            # [BK, BG]
         acc = jnp.dot(onehot, wd, preferred_element_type=jnp.float32)
 
         @pl.when(pid_g == 0)
@@ -78,16 +82,24 @@ def _sweep_kernel(xt_ref, r_ref, wd_ref, out_ref, acc_ref, *, bc: int,
 
     @pl.when(pid_g == n_g - 1)
     def _epilogue():
+        # Candidate c's scalar Θ' partial fills row c of a [BC, 128] tile:
+        # Mosaic stores vectors, never scalars, into VMEM.
+        row = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+        partials = jnp.zeros(out_ref.shape, jnp.float32)
         for c in range(bc):
-            partial = EPILOGUES[delta](acc_ref[c]).sum()    # scalar Θ' partial
+            partials = jnp.where(
+                row == c,
+                EPILOGUES[delta](acc_ref[c], keepdims=True).sum(
+                    axis=0, keepdims=True),
+                partials)
 
-            @pl.when(pid_k == 0)
-            def _first_tile(partial=partial, c=c):
-                out_ref[c, 0] = partial
+        @pl.when(pid_k == 0)
+        def _first_tile():
+            out_ref[...] = partials
 
-            @pl.when(pid_k != 0)
-            def _later_tiles(partial=partial, c=c):
-                out_ref[c, 0] += partial
+        @pl.when(pid_k != 0)
+        def _later_tiles():
+            out_ref[...] += partials
 
 
 @functools.partial(
@@ -106,12 +118,14 @@ def sweep_theta_pallas(
     bc: int = DEFAULT_BC,
     bk: int = DEFAULT_BK,
     bg: int = DEFAULT_BG,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Unnormalized Θ' partials [nc]; see module docstring for the schedule.
 
     The caller applies the measure's sign/|U| normalization
-    (``ops.sweep_theta``).
+    (``ops.sweep_theta``).  On a TPU ``bc`` must be a multiple of 8 (the
+    candidate axis is the block's sublane axis) or cover every candidate;
+    ``interpret=None`` compiles on a TPU and interprets elsewhere.
     """
     if delta not in EPILOGUES:
         raise ValueError(f"unknown measure: {delta}")
@@ -141,12 +155,12 @@ def sweep_theta_pallas(
             pl.BlockSpec((1, bg), lambda b, k, g_: (0, g_)),
             pl.BlockSpec((bg, m), lambda b, k, g_: (g_, 0)),
         ],
-        out_specs=pl.BlockSpec((bc, 1), lambda b, k, g_: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((c_pad, 1), jnp.float32),
+        out_specs=pl.BlockSpec((bc, LANE), lambda b, k, g_: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((c_pad, LANE), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bc, bk, m), jnp.float32)],
         cost_estimate=_cost_estimate(
             model.sweep_cost(nc, g, n_bins, m, bc, bk, bg, v_max=v_max,
                              delta=delta)),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x_t, r_ids.reshape(1, -1), wd)
     return out[:nc, 0]

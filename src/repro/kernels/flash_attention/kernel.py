@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_mode
+
 DEFAULT_BQ = 128
 DEFAULT_BKV = 128
 NEG_INF = -1e30
@@ -106,7 +108,7 @@ def flash_attention(
     scale: Optional[float] = None,
     bq: int = DEFAULT_BQ,
     bkv: int = DEFAULT_BKV,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     b, hq, sq, dh = q.shape
     hkv, skv = k.shape[1], k.shape[2]
@@ -148,6 +150,6 @@ def flash_attention(
             pltpu.VMEM((bq_,), jnp.float32),
             pltpu.VMEM((bq_, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)
     return out[:, :, :sq, :]

@@ -26,7 +26,7 @@ def mha(
     window: Optional[int] = None,
     scale: Optional[float] = None,
     impl: str = "pallas",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Multi-head attention [B, H, S, Dh] with GQA kv broadcast."""
     if impl == "pallas":

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_mode
+
 DEFAULT_CHUNK = 128
 
 
@@ -77,7 +79,7 @@ def rwkv6_scan(
     init_state: Optional[jnp.ndarray] = None,  # [B, H, D, D]
     *,
     chunk: int = DEFAULT_CHUNK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     b, h, t, d = r.shape
     l = min(chunk, t)
@@ -121,6 +123,6 @@ def rwkv6_scan(
             jax.ShapeDtypeStruct((bh, d, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(r_, k_, v_, w_, u_, s0)
     return o[:, :t].reshape(b, h, t, d), s_fin.reshape(b, h, d, d)

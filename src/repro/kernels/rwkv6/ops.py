@@ -19,7 +19,7 @@ def rwkv6(
     *,
     impl: str = "pallas",
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """RWKV6 recurrence over [B, H, T, D]; returns (outputs, final state)."""
     if impl == "pallas":

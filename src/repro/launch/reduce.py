@@ -71,6 +71,10 @@ def main():
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
+
     from repro.data import paper_dataset, scaled_paper_dataset
 
     if args.stream:

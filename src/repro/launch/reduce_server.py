@@ -12,7 +12,7 @@ device-resident granularity (one monoid merge), and *repairs* the previous
 reduct (warm-started selection) instead of recomputing it — the per-update
 latency column against the from-scratch recompute at the end is the point
 of the subsystem.  The final reduct is checked against a batch
-``plar_reduce`` over the full table.
+``plar_reduce`` over the full table; the process exits 1 when they differ.
 
 ``--clients K`` adds K concurrent mixed-measure clients per round: their
 queries land in one scheduler window and are served by stacked batched
@@ -85,6 +85,47 @@ def _stats_reporter(interval: float, registries, stop: threading.Event):
     return t
 
 
+async def stream_updates(srv, x, d, *, n_dec: int, v_max: int, delta: str,
+                         client_measures=(), updates: int = 4,
+                         name: str = "live"):
+    """Serve ``(x, d)`` as a live stream on a started server ``srv``.
+
+    The first half creates dataset ``name`` (unless a checkpoint restored
+    it), the rest arrives in ``updates`` row batches, and after every batch
+    one ``delta`` query plus one query per ``client_measures`` entry land in
+    the same scheduler window.  Returns ``(final_result, events)``: the last
+    ``delta`` result and one record per round.
+    """
+    half = len(x) // 2
+    rest = len(x) - half
+    if name not in srv._handles:  # absent unless restored (§3.10)
+        await srv.submit(name, x[:half], d[:half], n_dec=n_dec, v_max=v_max)
+    events = []
+
+    async def round_query(tag, rows):
+        t0 = time.perf_counter()
+        coros = [srv.query(name, delta=delta)]
+        coros += [srv.query(name, delta=m) for m in client_measures]
+        rs = await asyncio.gather(*coros)
+        req = srv.requests[-1]
+        events.append({
+            "event": tag, "rows": rows,
+            "granules": srv.handle(name).n_granules,
+            "reduct": rs[0].reduct,
+            "prefix_kept": req.prefix_kept,
+            "clients": 1 + len(client_measures),
+            "latency_s": round(time.perf_counter() - t0, 3)})
+        return rs[0]
+
+    r = await round_query("cold", half)
+    for i in range(updates):
+        lo = half + i * rest // updates
+        hi = half + (i + 1) * rest // updates
+        await srv.update(name, x[lo:hi], d[lo:hi])
+        r = await round_query(f"update_{i + 1}", hi - lo)
+    return r, events
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="kdd99")
@@ -122,6 +163,10 @@ def main():
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+
+    compile_cache.configure()
+
     from repro import obs
     from repro.core import plar_reduce
     from repro.data import scaled_paper_dataset
@@ -133,8 +178,6 @@ def main():
     stream = scaled_paper_dataset(args.dataset, max_rows=args.rows,
                                   max_attrs=args.attrs)
     x, d = stream.table()
-    half = len(x) // 2
-    rest = len(x) - half
 
     # K extra clients fan across the other measures (round-robin): a window
     # of mixed-measure queries per round, served by ONE stacked dispatch
@@ -161,33 +204,11 @@ def main():
 
     async def drive():
         async with server as srv:
-            if "live" not in srv._handles:  # absent unless restored (§3.10)
-                await srv.submit("live", x[:half], d[:half],
-                                 n_dec=stream.n_dec, v_max=stream.v_max)
-            events = []
-
-            async def round_query(tag, rows):
-                t0 = time.perf_counter()
-                coros = [srv.query("live", delta=args.delta)]
-                coros += [srv.query("live", delta=m) for m in client_measures]
-                rs = await asyncio.gather(*coros)
-                req = srv.requests[-1]
-                events.append({
-                    "event": tag, "rows": rows,
-                    "granules": srv.handle("live").n_granules,
-                    "reduct": rs[0].reduct,
-                    "prefix_kept": req.prefix_kept,
-                    "clients": 1 + len(client_measures),
-                    "latency_s": round(time.perf_counter() - t0, 3)})
-                return rs[0]
-
-            r = await round_query("cold", half)
-            for i in range(args.updates):
-                lo = half + i * rest // args.updates
-                hi = half + (i + 1) * rest // args.updates
-                await srv.update("live", x[lo:hi], d[lo:hi])
-                r = await round_query(f"update_{i + 1}", hi - lo)
-            return r, events, dict(srv.stats), srv.metrics.summary()
+            final, events = await stream_updates(
+                srv, x, d, n_dec=stream.n_dec, v_max=stream.v_max,
+                delta=args.delta, client_measures=client_measures,
+                updates=args.updates)
+            return final, events, dict(srv.stats), srv.metrics.summary()
 
     try:
         final, events, stats, metrics = asyncio.run(drive())
@@ -247,7 +268,8 @@ def main():
                   f"flushed={stats['flushed_batches']}")
         print(f"final reduct matches batch plar_reduce: "
               f"{out['reduct_matches_batch']}")
+    return 0 if out["reduct_matches_batch"] else 1
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

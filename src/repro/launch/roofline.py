@@ -1,16 +1,18 @@
 """Roofline terms from compiled dry-run artifacts (no hardware required).
 
-Hardware constants: TPU v5e-class — 197 bf16 TFLOP/s, 819 GB/s HBM,
-~50 GB/s/link ICI (per the brief).  This module is the one home for those
-constants: the analytic kernel-cost selector
+Hardware constants live in one table, :data:`PEAKS`, keyed by the
+``device_kind`` JAX reports.  The analytic kernel-cost selector
 (:mod:`repro.kernels.contingency.model`) ranks candidate tilings on the
-same :func:`roofline_terms` bound.
+same :func:`roofline_terms` bound.  On a TPU the running chip's row is
+used and a kind missing from the table is an error; on any other platform
+(the CPU and the Pallas interpreter) tiles are ranked against the v5e row,
+named explicitly by :data:`REFERENCE_KIND`.
 
 Three terms per (arch × shape × mesh), all in seconds-per-step:
 
-    compute    = HLO_FLOPs_per_device / 197e12
-    memory     = HLO_bytes_per_device / 819e9
-    collective = wire_bytes_per_device / 50e9
+    compute    = HLO_FLOPs_per_device / peak FLOP/s
+    memory     = HLO_bytes_per_device / peak HBM bytes/s
+    collective = wire_bytes_per_device / ICI bytes/s per link
 
 FLOPs/bytes come from ``compiled.cost_analysis()``.  CAVEAT (measured, see
 EXPERIMENTS.md §Dry-run): XLA's cost analysis counts a ``while`` body ONCE,
@@ -37,9 +39,34 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link
+# Published per-chip peaks.  Source: Google Cloud documentation, "TPU v5e"
+# (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect over 4 links = 50 GB/s per link).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+# The row that non-TPU platforms (CPU, the Pallas interpreter) rank against.
+REFERENCE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device=None) -> Dict[str, float]:
+    """Peaks of ``device`` (default: the first JAX device).
+
+    A TPU whose kind is not in :data:`PEAKS` raises: ranking a chip against
+    another chip's roofline would be silently wrong.  Other platforms get
+    the :data:`REFERENCE_KIND` row.
+    """
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return PEAKS[REFERENCE_KIND]
+    if device.device_kind not in PEAKS:
+        raise ValueError(
+            f"no roofline peaks for TPU kind {device.device_kind!r}; add its "
+            f"published peaks to PEAKS (known: {', '.join(sorted(PEAKS))})")
+    return PEAKS[device.device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -217,10 +244,13 @@ def model_flops(cfg, shape) -> float:
     return 2.0 * n_active * b + attn
 
 
-def roofline_terms(flops_dev: float, bytes_dev: float, wire_bytes_dev: float):
-    compute = flops_dev / PEAK_FLOPS
-    memory = bytes_dev / HBM_BW
-    collective = wire_bytes_dev / ICI_BW
+def roofline_terms(flops_dev: float, bytes_dev: float, wire_bytes_dev: float,
+                   peaks: Optional[Dict[str, float]] = None):
+    """Seconds per step under each peak of ``peaks`` (default: this chip's)."""
+    peaks = chip_peaks() if peaks is None else peaks
+    compute = flops_dev / peaks["flops"]
+    memory = bytes_dev / peaks["hbm_bw"]
+    collective = wire_bytes_dev / peaks["ici_bw"]
     dominant = max(
         [("compute", compute), ("memory", memory), ("collective", collective)],
         key=lambda kv: kv[1],

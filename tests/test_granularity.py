@@ -168,3 +168,19 @@ def test_granularity_property(n, a, vmax, seed):
     uniq, counts = _np_granules(x, d)
     assert int(g.num) == len(uniq)
     assert int(np.asarray(g.w).sum()) == n
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_exact_class_ids_number_like_lexsort(seed):
+    # sort-free exact grouping numbers classes exactly as a lexsort with
+    # column 0 as the primary key does, invalid rows at id 0
+    from repro.core.granularity import exact_class_ids
+    from repro.core.plan import ids_by_sort
+
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.integers(0, 4, (300, 5)), jnp.int32)
+    valid = jnp.asarray(rng.random(300) < 0.9)
+    ids, k = exact_class_ids(x, valid, radix=4)
+    ref_ids, ref_k = ids_by_sort([x[:, j] for j in range(5)][::-1], valid)
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(ref_ids))
+    assert int(k) == int(ref_k)

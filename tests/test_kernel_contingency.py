@@ -86,3 +86,12 @@ def test_contingency_property(nc, g, n_bins, m, seed):
     out = contingency(packed, d, w, n_bins=n_bins, n_dec=m)
     ref = contingency_ref(packed, d, w, n_bins=n_bins, n_dec=m)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6, atol=1e-5)
+
+
+def test_interpret_mode_follows_platform():
+    import jax
+
+    from repro.kernels.platform import interpret_mode
+
+    assert interpret_mode() == (jax.default_backend() != "tpu")
+    assert interpret_mode(True) is True and interpret_mode(False) is False

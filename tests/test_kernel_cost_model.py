@@ -313,6 +313,19 @@ def test_disk_tuned_overrides_model(tmp_disk):
     assert resolve_tiles("fused", **kw, selector="heuristic") != custom
 
 
+def test_no_disk_cache_unless_named(monkeypatch, tmp_path):
+    # tiles come from the model alone unless REPRO_AUTOTUNE_CACHE names a
+    # file: no default location is read or written
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE", raising=False)
+    monkeypatch.setenv("HOME", str(tmp_path))
+    monkeypatch.setenv("XDG_CACHE_HOME", str(tmp_path))
+    autotune_cache_clear()
+    pick = autotune_block_sizes(2, 300, 40, 3)
+    assert autotune_cache_info()["disk_path"] is None
+    assert list(tmp_path.iterdir()) == []
+    assert resolve_tiles("contingency", nc=2, g=300, n_bins=40, m=3) == pick
+
+
 def test_restricted_candidates_not_persisted(tmp_disk):
     # a rank over a caller-pinned candidate list is not a shape tuning and
     # must not shadow the model for the whole bucket

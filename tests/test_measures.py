@@ -110,3 +110,15 @@ def test_theta_property(n, a, vmax, m, delta, seed):
     got = _theta_via_decomposition(delta, x, d, [int(c) for c in cols])
     want = theta_oracle(delta, x, d, [int(c) for c in cols])
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("delta", DELTAS)
+def test_raw_rows_keepdims_same_values(delta):
+    # the [..., K, 1] column form the Pallas epilogues use holds the same
+    # bits as the default [..., K] rows
+    rng = np.random.default_rng(3)
+    cont = jnp.asarray(rng.integers(0, 5, (4, 16, 7)), jnp.float32)
+    rows = measures.RAW_ROWS[delta](cont)
+    col = measures.RAW_ROWS[delta](cont, keepdims=True)
+    assert col.shape == rows.shape + (1,)
+    np.testing.assert_array_equal(np.asarray(col[..., 0]), np.asarray(rows))

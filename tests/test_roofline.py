@@ -74,3 +74,18 @@ def test_window_caps_attention_span():
     mf_500k = rl.model_flops(cfg, SHAPES["long_500k"])
     # one token, window 32k on 9 attention layers: far below a dense-attn arch
     assert mf_500k < 2.5 * cfg.active_param_count()
+
+
+class _Dev:
+    def __init__(self, platform, device_kind):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    v5e = rl.chip_peaks(_Dev("tpu", "TPU v5 lite"))
+    assert v5e["flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    # off the TPU (CPU, the Pallas interpreter) tiles rank on the named row
+    assert rl.chip_peaks(_Dev("cpu", "cpu")) is rl.PEAKS[rl.REFERENCE_KIND]
+    with pytest.raises(ValueError, match="no roofline peaks"):
+        rl.chip_peaks(_Dev("tpu", "TPU v99"))
