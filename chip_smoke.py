@@ -8,10 +8,15 @@ One chip, one process (a chip belongs to one process at a time):
 
 1. device   — platform, kind and count; anything but a TPU exits 1.
 2. kdd99    — the paper's flagship at its Table-5 shape, 5,000,000 × 41
-              generated from ``--seed``, streamed chunkwise onto the device
-              by ``plar_reduce(source=...)`` and reduced by the device
-              engine.  Θ(D|R) is then recomputed from a second, independent
-              streamed build over R's columns and must reach Θ(D|C).
+              generated from :data:`SEED`, streamed chunkwise onto the
+              device by ``plar_reduce(source=...)`` and reduced by the
+              device engine.  A second stream rebuilds the granules while a
+              numpy witness counts the distinct rows of C ∪ D and of R ∪ D
+              on the host: the granule count, the (R-class, decision) cell
+              count and Θ(D|C), Θ(D|R) recomputed on the chip must match it,
+              and Θ(D|R) must reach Θ(D|C).  The engine then runs again on
+              the rebuilt granules, already compiled, which times the greedy
+              loop alone.
 3. mushroom — the full 5644 × 22 table: the device engine's reduct equals
               ``core/oracle.py``'s, and its Θ history is byte-identical to
               the host ``segment`` loop's.
@@ -25,7 +30,8 @@ One chip, one process (a chip belongs to one process at a time):
 ``--chips 4`` runs ``plar_reduce_distributed`` (segment backend, the
 ``all_reduce`` and ``reduce_scatter`` collectives) on a 2×2 mesh of the four
 chips and compares each reduct with single-chip ``plar_reduce`` on the same
-table.
+table; it also checks that each device of the mesh holds the granules of
+its own data shard.
 
 Any failed check exits non-zero.  The last stdout line is one JSON object,
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``,
@@ -53,6 +59,8 @@ KDD99_MP_CHUNK = 8
 # v5e's HBM (kdd99 and weka15360 do not: the mesh evaluates every candidate
 # of a model shard at once).
 MESH_TABLE = "gisette"
+# The seed every table is generated from.
+SEED = 0
 
 
 class Failed(Exception):
@@ -98,9 +106,46 @@ class CompileCounter:
         jax.monitoring.register_event_listener(on_event)
 
 
-def phase_kdd99(seed: int) -> None:
+class DistinctRows:
+    """Distinct rows of a stream of row blocks and their weights, in numpy
+    on the host: the witness the device's grouping is checked against.
+    Rows are compared as byte strings; values must be below 256."""
+
+    def __init__(self):
+        self.keys = None
+        self.counts = None
+
+    def add(self, rows, weights=None):
+        import numpy as np
+
+        rows = np.ascontiguousarray(rows, np.uint8)
+        keys = rows.view(np.dtype((np.void, rows.shape[1])))[:, 0]
+        counts = np.ones(len(keys), np.int64) if weights is None else weights
+        if self.keys is not None:
+            keys = np.concatenate([self.keys, keys])
+            counts = np.concatenate([self.counts, counts])
+        self.keys, inv = np.unique(keys, return_inverse=True)
+        self.counts = np.bincount(inv, weights=counts).astype(np.int64)
+
+    def sce(self) -> float:
+        """Θ_SCE(D | the other columns), the last column being D:
+        -Σ c·log(c/e) / n over the (class, decision) cells."""
+        import numpy as np
+
+        width = self.keys.dtype.itemsize
+        rows = self.keys.view(np.uint8).reshape(-1, width)
+        cond = np.ascontiguousarray(rows[:, :-1])
+        _, cls = np.unique(cond.view(np.dtype((np.void, width - 1)))[:, 0],
+                           return_inverse=True)
+        c = self.counts.astype(np.float64)
+        e = np.bincount(cls, weights=c)[cls]
+        return float(-(c * np.log(c / e)).sum() / c.sum())
+
+
+def phase_kdd99() -> None:
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from repro import obs
     from repro.core import measures
@@ -109,7 +154,7 @@ def phase_kdd99(seed: int) -> None:
     from repro.core.reduction import plar_reduce
     from repro.data import paper_dataset
 
-    stream = paper_dataset("kdd99", seed=seed)
+    stream = paper_dataset("kdd99", seed=SEED)
     print(f"  table {stream.n_rows} x {stream.n_attrs}, {stream.n_dec} "
           f"classes, v_max {stream.v_max}; SCE, chunk_rows 65536, "
           f"mp_chunk {KDD99_MP_CHUNK}, greedy phase unbounded", flush=True)
@@ -120,7 +165,6 @@ def phase_kdd99(seed: int) -> None:
                     mp_chunk=KDD99_MP_CHUNK)
     wall = time.perf_counter() - t0
     recs = tracer.records()
-    obs.disable()
     folds = [s for s in recs if s.name == "pipeline.fold_chunk"]
     disp = [s for s in recs if s.name == "engine.dispatch"][-1]
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
@@ -135,33 +179,68 @@ def phase_kdd99(seed: int) -> None:
           f"engine stopped at Θ(D|R) {r.theta_history[-1]!r} within tol "
           f"of Θ(D|C) {r.theta_full!r}")
 
-    # Independent Θ(D|R): a second streamed GrC build over R's columns only,
-    # exact sort-based class ids, the plain segment contingency.
+    # A second stream rebuilds the granules on the chip while numpy counts
+    # the distinct rows of C ∪ D and R ∪ D on the host.
     cols = list(r.reduct)
+    wit_c, wit_r = DistinctRows(), DistinctRows()
+
+    def witnessed_chunks():
+        for x, d in stream.chunks(65536):
+            wit_c.add(np.column_stack([x, d]))
+            wit_r.add(np.column_stack([x[:, cols], d]))
+            yield x, d
+
     t0 = time.perf_counter()
-    g_r = build_granularity_streaming(
-        ((x[:, cols], d) for x, d in stream.chunks(65536)),
-        n_dec=stream.n_dec, v_max=stream.v_max)
-    ids, _ = subset_ids(g_r, jnp.arange(len(cols), dtype=jnp.int32),
-                        exact=True)
-    cont = contingency_from_ids(ids, g_r.d, g_r.w, g_r.valid,
-                                n_bins=g_r.capacity, m=g_r.n_dec)
-    theta_r = float(measures.evaluate("SCE", cont, g_r.n_total))
+    g = build_granularity_streaming(witnessed_chunks(), n_dec=stream.n_dec,
+                                    v_max=stream.v_max)
+    print(f"  second stream with the numpy witness "
+          f"{time.perf_counter() - t0:.1f}s", flush=True)
+    check(int(g.n_total) == stream.n_rows, "every row streamed")
+    check(int(g.num) == len(wit_c.keys),
+          f"{int(g.num)} granules == {len(wit_c.keys)} distinct rows of "
+          f"C ∪ D (numpy)")
+
+    # The engine again, on the rebuilt granules: compiled, so the dispatch
+    # is the greedy loop alone.
+    tracer.clear()
+    r2 = plar_reduce(source=g, delta="SCE", mp_chunk=KDD99_MP_CHUNK)
+    disp2 = [s for s in tracer.records() if s.name == "engine.dispatch"][-1]
+    obs.disable()
+    print(f"  second engine dispatch, no compile: {disp2.dur:.3f}s for "
+          f"{r2.iterations} iterations", flush=True)
+    check(not disp2.args["compiled"] and disp2.args["cap"] == disp.args["cap"],
+          "second dispatch reused the compiled engine")
+    check(r2.reduct == r.reduct and hist_bytes(r2) == hist_bytes(r),
+          "second run's reduct and Θ history == first run's")
+
+    # Θ(D|R) on the chip, outside the engine, against the witness
+    ids, _ = subset_ids(g, jnp.asarray(cols, jnp.int32), exact=True)
+    cont = contingency_from_ids(ids, g.d, g.w, g.valid, n_bins=g.capacity,
+                                m=g.n_dec)
+    theta_r = float(measures.evaluate("SCE", cont, g.n_total))
+    cells = int(jnp.count_nonzero(cont))
+    wit_theta_c, wit_theta_r = wit_c.sce(), wit_r.sce()
     # tol, plus the f32 rounding of a different summation order
     slack = 1e-6 + 2.0 ** -20 * abs(r.theta_full)
-    print(f"  recomputed Θ(D|R) {theta_r!r} from {int(g_r.num)} granules "
-          f"of (R ∪ D) in {time.perf_counter() - t0:.1f}s", flush=True)
-    check(int(g_r.n_total) == stream.n_rows, "every row streamed")
+    print(f"  Θ(D|C) engine {r.theta_full!r}, numpy {wit_theta_c!r}; "
+          f"Θ(D|R) chip {theta_r!r} over {cells} cells, numpy "
+          f"{wit_theta_r!r} over {len(wit_r.keys)} distinct rows of R ∪ D",
+          flush=True)
+    check(cells == len(wit_r.keys),
+          "(R-class, decision) cells == distinct rows of R ∪ D (numpy)")
+    check(abs(r.theta_full - wit_theta_c) <= slack
+          and abs(theta_r - wit_theta_r) <= slack,
+          f"Θ(D|C) and Θ(D|R) == numpy's within {slack:.3g}")
     check(theta_r - r.theta_full <= slack,
           f"recomputed Θ(D|R) reaches Θ(D|C) within {slack:.3g}")
 
 
-def phase_mushroom(seed: int) -> None:
+def phase_mushroom() -> None:
     from repro.core.oracle import reduct_oracle
     from repro.core.reduction import plar_reduce
     from repro.data import paper_dataset
 
-    t = paper_dataset("mushroom", seed=seed)
+    t = paper_dataset("mushroom", seed=SEED)
     x, d = t.table()
     kw = dict(delta="SCE", n_dec=t.n_dec, v_max=t.v_max)
     t0 = time.perf_counter()
@@ -178,7 +257,7 @@ def phase_mushroom(seed: int) -> None:
           "device Θ history byte-identical to the host segment loop's")
 
 
-def phase_kernels(seed: int) -> None:
+def phase_kernels() -> None:
     import jax
     import jax.numpy as jnp
 
@@ -203,7 +282,7 @@ def phase_kernels(seed: int) -> None:
         check("tpu_custom_call" in low.compile().as_text(),
               f"{name} kernel compiles to a Mosaic custom call")
 
-    t = paper_dataset("shuttle", seed=seed)
+    t = paper_dataset("shuttle", seed=SEED)
     x, d = t.table()
     kw = dict(delta="SCE", n_dec=t.n_dec, v_max=t.v_max, engine="host")
     t0 = time.perf_counter()
@@ -220,13 +299,13 @@ def phase_kernels(seed: int) -> None:
               f"{backend} reduct and Θ history == segment's")
 
 
-def phase_service(seed: int) -> None:
+def phase_service() -> None:
     from repro.core.reduction import plar_reduce
     from repro.data import paper_dataset
     from repro.launch.reduce_server import stream_updates
     from repro.service import ReductServer
 
-    t = paper_dataset("shuttle", seed=seed)
+    t = paper_dataset("shuttle", seed=SEED)
     x, d = t.table()
 
     async def drive():
@@ -253,15 +332,18 @@ def phase_service(seed: int) -> None:
           f"final reduct {final.reduct} == batch plar_reduce {batch.reduct}")
 
 
-def phase_mesh(seed: int) -> None:
+def phase_mesh() -> None:
     import jax
+    import numpy as np
 
-    from repro.core.distributed import plar_reduce_distributed
+    # the mesh driver's own ingestion, to look at where it put the granules
+    from repro.core.distributed import (_granularity_from_source,
+                                        plar_reduce_distributed)
     from repro.core.reduction import plar_reduce
     from repro.data import paper_dataset
     from repro.distributed.api import make_mesh
 
-    stream = paper_dataset(MESH_TABLE, seed=seed)
+    stream = paper_dataset(MESH_TABLE, seed=SEED)
     print(f"  table {MESH_TABLE} {stream.n_rows} x {stream.n_attrs}",
           flush=True)
     t0 = time.perf_counter()
@@ -282,12 +364,43 @@ def phase_mesh(seed: int) -> None:
     check(len({d.id for d in mesh.devices.flat}) == len(jax.devices()),
           "the mesh spans every chip")
 
+    # Placement: the device that holds row block s of the granule table
+    # must hold the granules of data shard s — the rows s·n/S..(s+1)·n/S of
+    # every chunk — checked against numpy over the same slices.
+    nd = mesh.shape["data"]
+    wit = [DistinctRows() for _ in range(nd)]
+    for xc, dc in stream.chunks(65536):
+        n = len(xc)
+        for s in range(nd):
+            lo, hi = s * n // nd, (s + 1) * n // nd
+            wit[s].add(np.column_stack([xc[lo:hi], dc[lo:hi]]))
+    gx, gd, gw, gv, _ = _granularity_from_source(
+        stream, mesh, n_dec=stream.n_dec, v_max=stream.v_max,
+        chunk_rows=65536)
+    cap_ps = gx.shape[0] // nd
+    on_dev = {f: {sh.device: sh for sh in a.addressable_shards}
+              for f, a in (("x", gx), ("d", gd), ("w", gw), ("v", gv))}
+    held = []
+    for dev, sh in on_dev["x"].items():
+        s = (sh.index[0].start or 0) // cap_ps
+        live = np.asarray(on_dev["v"][dev].data)
+        got = DistinctRows()
+        got.add(np.column_stack([np.asarray(sh.data)[live],
+                                 np.asarray(on_dev["d"][dev].data)[live]]),
+                np.asarray(on_dev["w"][dev].data)[live].astype(np.int64))
+        held.append((s, dev.id, int(live.sum())))
+        check(np.array_equal(got.keys, wit[s].keys)
+              and np.array_equal(got.counts, wit[s].counts),
+              f"device {dev.id} holds data shard {s}'s "
+              f"{len(wit[s].keys)} granules")
+    check(len(held) == len(jax.devices()),
+          f"granule blocks on every chip (shard, device, granules): "
+          f"{sorted(held)}")
+
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
-    ap.add_argument("--seed", type=int, default=0,
-                    help="seed of every generated table")
     args = ap.parse_args()
 
     if not (ROOT / "src" / "repro").is_dir():
@@ -323,7 +436,7 @@ def main() -> int:
         print(f"[{name}]", flush=True)
         t0 = time.perf_counter()
         try:
-            fn(args.seed)
+            fn()
         except Failed:
             return 1
         print(f"[{name}] passed in {time.perf_counter() - t0:.1f}s "

@@ -37,7 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Tuple
 
 # Published per-chip peaks.  Source: Google Cloud documentation, "TPU v5e"
 # (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
@@ -244,10 +244,9 @@ def model_flops(cfg, shape) -> float:
     return 2.0 * n_active * b + attn
 
 
-def roofline_terms(flops_dev: float, bytes_dev: float, wire_bytes_dev: float,
-                   peaks: Optional[Dict[str, float]] = None):
-    """Seconds per step under each peak of ``peaks`` (default: this chip's)."""
-    peaks = chip_peaks() if peaks is None else peaks
+def roofline_terms(flops_dev: float, bytes_dev: float, wire_bytes_dev: float):
+    """Seconds per step under each of this chip's peaks (:func:`chip_peaks`)."""
+    peaks = chip_peaks()
     compute = flops_dev / peaks["flops"]
     memory = bytes_dev / peaks["hbm_bw"]
     collective = wire_bytes_dev / peaks["ici_bw"]
