@@ -254,6 +254,7 @@ class _MeshColl:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("advance")
 def _advance(cfg, coll, r_ids, x_col, d, w, active, n, eval_theta=None):
     """Fold one attribute into the class ids: pack → compact → Θ → purity.
 
@@ -298,6 +299,7 @@ def _rung_index(cfg, k):
     return jnp.sum(need > jnp.asarray(cfg.rungs, jnp.int32)).astype(jnp.int32)
 
 
+@jax.named_scope("eval_candidates")
 def _eval_local(cfg: _Cfg, st: SelectionState, x, x_t, d, w, n):
     """Single-process candidate evaluation: Θ(D|R∪{a}) for every a, [A].
 
@@ -457,11 +459,14 @@ def _make_cond_body(cfg: _Cfg, coll, eval_thetas, x, d, w, n, theta_full,
             return core_attrs[jnp.minimum(st.n_selected, cfg.n_attrs - 1)]
 
         def pick_greedy(st):
-            thetas = jnp.where(st.remaining, eval_thetas(st), jnp.inf)
+            thetas = eval_thetas(st)
             # lowest index within tie_tol of the minimum — the device twin of
             # measures.argmin_with_ties (remaining is index-ordered, so the
             # first in-band slot is the same attribute the host loop picks).
-            return jnp.argmax(thetas <= thetas.min() + cfg.tie_tol).astype(jnp.int32)
+            with jax.named_scope("select"):
+                thetas = jnp.where(st.remaining, thetas, jnp.inf)
+                return jnp.argmax(
+                    thetas <= thetas.min() + cfg.tie_tol).astype(jnp.int32)
 
         best = jax.lax.cond(forced, pick_core, pick_greedy, st)
         x_col = jnp.take(x, best, axis=1)

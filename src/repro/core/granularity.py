@@ -302,6 +302,9 @@ def merge_granularity(a: Granularity, b: Granularity, *, exact: bool = True,
         num = int(g.num)
         if num <= cap:
             return g
+        obs.counter("plar_merge_rebuilds_total",
+                    "merges built again because their granules overflowed "
+                    "the capacity").inc()
         cap = next_pow2(num)
 
 
@@ -344,24 +347,35 @@ def fold_chunk(acc: Optional[Granularity], xc, dc, *, n_dec: int, v_max: int,
     chunk's granularity is far smaller than the chunk, and the merge sort
     should pay for live keys, not padding.  The host syncs are the per-merge
     count() the policy already requires.
+
+    ``ingest.h2d`` times the host's issue of the chunk's copies, not the
+    transfer: that ends under ``ingest.granulate``, whose build waits for
+    it (and arrays already on a device, as the distributed fold passes,
+    copy nothing).
     """
-    xc = jnp.asarray(xc, jnp.int32)
-    dc = jnp.asarray(dc, jnp.int32)
+    with obs.span("ingest.h2d", rows=len(xc), bytes=xc.nbytes + dc.nbytes):
+        xc = jnp.asarray(xc, jnp.int32)
+        dc = jnp.asarray(dc, jnp.int32)
     if xc.shape[0] == 0:
         return acc
     with obs.span("pipeline.fold_chunk", rows=int(xc.shape[0]),
                   fresh=acc is None) as sp:
-        g = build_granularity(
-            xc, dc, n_dec=n_dec, v_max=v_max, exact=exact, seed=seed,
-            capacity=next_pow2(xc.shape[0]),
-        )
-        g = with_capacity(g, next_pow2(max(int(g.num), 1)))
+        with obs.span("ingest.granulate") as gsp:
+            g = build_granularity(
+                xc, dc, n_dec=n_dec, v_max=v_max, exact=exact, seed=seed,
+                capacity=next_pow2(xc.shape[0]),
+            )
+            g = with_capacity(g, next_pow2(max(int(g.num), 1)))
+            gsp.set(capacity=g.capacity)
         if acc is None:
             sp.set(granules=int(g.num))
             return g
-        acc = merge_granularity(acc, g, exact=exact, seed=seed)
-        acc = with_capacity(acc, next_pow2(max(int(acc.num), 1)))
-        sp.set(granules=int(acc.num))
+        with obs.span("ingest.merge") as msp:
+            acc = merge_granularity(acc, g, exact=exact, seed=seed)
+            num = int(acc.num)
+            acc = with_capacity(acc, next_pow2(max(num, 1)))
+            msp.set(capacity=acc.capacity, granules=num)
+        sp.set(granules=num)
     return acc
 
 
@@ -433,8 +447,9 @@ def exact_class_ids(cols: jnp.ndarray, valid: jnp.ndarray, radix: int):
         p = ids * radix + jnp.take(cols, j, axis=1).astype(jnp.int32)
         return ids_from_presence(presence_bitmap(p, valid, n_bins), p, valid)
 
-    init = (jnp.zeros((n,), jnp.int32), jnp.any(valid).astype(jnp.int32))
-    return jax.lax.fori_loop(0, k, fold, init)
+    with jax.named_scope("group_columns"):
+        init = (jnp.zeros((n,), jnp.int32), jnp.any(valid).astype(jnp.int32))
+        return jax.lax.fori_loop(0, k, fold, init)
 
 
 @partial(jax.jit, static_argnames=("n_bins",))

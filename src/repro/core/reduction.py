@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 from . import measures
 from .engine import (
     DEVICE_BACKENDS,
@@ -224,6 +226,12 @@ def _make_advance(n_bins, v_max, m, delta):
 # ---------------------------------------------------------------------------
 
 
+def _core_path(exact: bool, n_attrs: int) -> str:
+    """How :func:`_core_inner_thetas` groups: ``"exact"`` (one exact
+    grouping per attribute) or ``"sketch"`` (fingerprint sorts)."""
+    return "exact" if exact and n_attrs <= 128 else "sketch"
+
+
 def _core_inner_thetas(gran: Granularity, delta: str, *, exact: bool, chunk: int = 64) -> np.ndarray:
     """Θ(D|C\\{a}) for every a ∈ C (paper lines 3–8, the MP'd core step)."""
     A = gran.n_attrs
@@ -231,7 +239,7 @@ def _core_inner_thetas(gran: Granularity, delta: str, *, exact: bool, chunk: int
     n_bins = cap  # ≤ G distinct classes always
     out = np.zeros((A,), np.float64)
 
-    if exact and A <= 128:
+    if _core_path(exact, A) == "exact":
         for a in range(A):
             cols = jnp.asarray([j for j in range(A) if j != a], jnp.int32)
             ids, _ = subset_ids(gran, cols, exact=True)
@@ -454,199 +462,210 @@ def plar_reduce(
             f"unknown selector: {selector!r} "
             f"(one of: {', '.join(SELECTOR_MODES)})")
     engine = _resolve_engine(engine, backend)
-    gran = resolve_granularity(
-        x, d, source=source, grc_init=grc_init, n_dec=n_dec, v_max=v_max,
-        exact=exact, chunk_rows=chunk_rows)
+    kind = ("arrays" if source is None else
+            "granules" if isinstance(source, Granularity) else "rows")
+    with obs.span("reduction.plar_reduce", delta=delta, engine=engine,
+                  source=kind) as root:
+        gran = resolve_granularity(
+            x, d, source=source, grc_init=grc_init, n_dec=n_dec, v_max=v_max,
+            exact=exact, chunk_rows=chunk_rows)
 
-    A = gran.n_attrs
-    m = gran.n_dec
-    cap = gran.capacity
-    n = gran.n_total
-    n_evals = 0
+        A = gran.n_attrs
+        m = gran.n_dec
+        cap = gran.capacity
+        n = gran.n_total
+        n_evals = 0
+        root.set(A=A, capacity=cap)
 
-    warm: Optional[List[int]] = None
-    if warm_start is not None:
-        warm = _validate_warm_start(warm_start, A)
+        warm: Optional[List[int]] = None
+        if warm_start is not None:
+            warm = _validate_warm_start(warm_start, A)
 
-    # Θ(D|C): stopping target.
-    all_cols = jnp.arange(A, dtype=jnp.int32)
-    ids_c, _k = subset_ids(gran, all_cols, exact=exact)
-    cont_c = contingency_from_ids(ids_c, gran.d, gran.w, gran.valid, n_bins=cap, m=m)
-    theta_full = float(measures.evaluate(delta, cont_c, n))
+        # Θ(D|C): stopping target.
+        with obs.span("reduction.theta_full"):
+            all_cols = jnp.arange(A, dtype=jnp.int32)
+            ids_c, _k = subset_ids(gran, all_cols, exact=exact)
+            cont_c = contingency_from_ids(ids_c, gran.d, gran.w, gran.valid,
+                                          n_bins=cap, m=m)
+            theta_full = float(measures.evaluate(delta, cont_c, n))
 
-    # --- core (skipped under warm_start: the prefix stands in for it) ---
-    core: List[int] = []
-    if compute_core and warm is None:
-        inner = _core_inner_thetas(gran, delta, exact=exact)
-        sig = inner - theta_full  # Θ(D|C\{a}) - Θ(D|C)
-        core = [int(a) for a in range(A) if sig[a] > eps + tie_tol]
-        n_evals += A
-    forced = core if warm is None else warm
+        # --- core (skipped under warm_start: the prefix stands in for it) ---
+        core: List[int] = []
+        if compute_core and warm is None:
+            with obs.span("reduction.core", A=A,
+                          path=_core_path(exact, A)):
+                inner = _core_inner_thetas(gran, delta, exact=exact)
+            sig = inner - theta_full  # Θ(D|C\{a}) - Θ(D|C)
+            core = [int(a) for a in range(A) if sig[a] > eps + tie_tol]
+            n_evals += A
+        forced = core if warm is None else warm
 
-    if engine == "device":
-        # Device-resident engine: core folding + greedy loop + stopping rule
-        # run as ONE lax.while_loop (core/engine.py) — a single dispatch, a
-        # single compile (n_bins = cap·v_max is static), and one device→host
-        # transfer at the end.
-        max_sel = int(max_features) if max_features is not None else A
-        runner = make_engine_run(
-            delta, mode, backend, A, cap, m, gran.v_max, float(tol),
-            float(tie_tol), bool(shrink), max_sel, int(mp_chunk),
-            bool(ladder), str(selector))
-        reduct, theta_hist, iterations, ev, per_iter = run_engine(
-            runner, cap, A, gran.valid, gran.x, gran.d, gran.w, n,
-            theta_full, core, warm_start=warm)
+        if engine == "device":
+            # Device-resident engine: core folding + greedy loop + stopping rule
+            # run as ONE lax.while_loop (core/engine.py) — a single dispatch, a
+            # single compile (n_bins = cap·v_max is static), and one device→host
+            # transfer at the end.
+            max_sel = int(max_features) if max_features is not None else A
+            runner = make_engine_run(
+                delta, mode, backend, A, cap, m, gran.v_max, float(tol),
+                float(tie_tol), bool(shrink), max_sel, int(mp_chunk),
+                bool(ladder), str(selector))
+            reduct, theta_hist, iterations, ev, per_iter = run_engine(
+                runner, cap, A, gran.valid, gran.x, gran.d, gran.w, n,
+                theta_full, core, warm_start=warm)
+            root.set(k=len(reduct))
+            return ReductionResult(
+                reduct=reduct,
+                core=core,
+                theta_full=theta_full,
+                theta_history=theta_hist,
+                iterations=iterations,
+                n_evaluations=n_evals + ev,
+                elapsed_s=time.perf_counter() - t0,
+                per_iteration_s=per_iter,
+            )
+
+        # --- greedy loop state (engine == "host": the legacy escape hatch) ---
+        r_ids = jnp.zeros((cap,), jnp.int32)
+        k = 1
+        active = gran.valid
+        # float32 accumulation, mirroring the device engine bit-for-bit (so the
+        # two engines' theta histories are byte-identical, asserted in tests)
+        pr_correction = np.float32(0.0)
+        reduct: List[int] = []
+        theta_hist: List[float] = []
+        per_iter_s: List[float] = []
+
+        v = gran.v_max
+
+        # The advance (and, ladder off, the evaluation) uses the engine's static
+        # bin bound cap·V: one compile for the whole run (no power-of-two
+        # recompile ladder) and Θ summed over the same padded rows as
+        # engine="device" — zero rows add exactly 0 in f32, but reduction
+        # *grouping* depends on length, so equal lengths ⇒ equal bits (candidate
+        # thetas AND recorded histories).  The §5.3 ladder shrinks only the
+        # *candidate evaluation* bins; the advance keeps the full bound, which is
+        # what keeps theta histories byte-identical across every (backend,
+        # ladder) combination.
+        adv = _make_advance(cap * v, v, m, delta)
+
+        # K-adaptive candidate-eval bins (ladder on): the host twin of the
+        # engine's lax.switch — same static rung set, chosen per iteration from
+        # the synced k, one (lru-cached) compile per rung actually visited.
+        # The selector-pruned set is a function of (cap, m) only, so host and
+        # device engines derive identical rungs (byte parity, DESIGN.md §5.3).
+        rungs = ladder_rungs(cap * v, selector=selector, g=cap, m=m)
+
+        def _eval_bins_for(k_):
+            if ladder:
+                return rung_for(k_, v, rungs)
+            # device-capable backends pin the full static bound for bit parity
+            # with engine="device"; host-only Pallas backends keep the cheaper
+            # pow2 ladder (no device twin to match)
+            return cap * v if backend in DEVICE_BACKENDS else _next_pow2(max(k_, 1)) * v
+
+        # read-once candidate slab for the sweep backends, hoisted out of the
+        # loop (the device engine hoists the same transpose before its while_loop)
+        x_t_full = jnp.swapaxes(gran.x, 0, 1) if backend in SWEEP_BACKENDS else None
+
+        # The stop threshold mirrors the device cond's f32 arithmetic exactly, so
+        # both engines run the same number of iterations even when theta_r lands
+        # within an ulp of it.
+        stop_thresh = measures.f32_threshold(theta_full, tol)
+
+        def _shrink_step(g_pure):
+            nonlocal pr_correction, active
+            if delta == "PR":
+                shed = jnp.sum(jnp.where(g_pure, gran.w, 0)).astype(jnp.float32)
+                pr_correction = pr_correction - np.float32(shed / jnp.float32(n))
+            active = active & ~g_pure
+
+        # fold the forced prefix (core attributes, or the warm-start prefix)
+        for a in forced:
+            r_ids, k_new, theta_r, g_pure = adv(r_ids, gran.x[:, a], gran.d, gran.w, active, n)
+            k = int(k_new)
+            reduct.append(a)
+            theta_hist.append(float(np.float32(theta_r) + pr_correction))
+            if shrink:
+                _shrink_step(g_pure)
+
+        theta_r = theta_hist[-1] if theta_hist else float("inf")
+
+        remaining = [a for a in range(A) if a not in reduct]
+        iterations = 0
+        while remaining and theta_r > stop_thresh:
+            if max_features is not None and len(reduct) >= max_features:
+                break
+            it0 = time.perf_counter()
+            nc = min(mp_chunk, max(len(remaining), 1))
+
+            thetas = np.full((len(remaining),), np.inf, np.float64)
+            if mode == "spark":
+                # re-key from scratch: fingerprint of current R columns
+                if reduct:
+                    hR1 = sum_terms(gran.x, reduct, 0)
+                    hR2 = sum_terms(gran.x, reduct, 7919)
+                else:
+                    hR1 = jnp.zeros((cap,), jnp.uint32)
+                    hR2 = jnp.zeros((cap,), jnp.uint32)
+                runner = _eval_chunk_spark(delta, cap, m, v)
+                for s in range(0, len(remaining), nc):
+                    cols = np.asarray(remaining[s : s + nc], np.int32)
+                    pad = nc - len(cols)
+                    padded = np.concatenate([cols, np.full((pad,), cols[-1], np.int32)])
+                    vals = np.asarray(
+                        runner(hR1, hR2, jnp.asarray(padded), gran.x, gran.d, gran.w, active, n, pr_correction)
+                    )
+                    thetas[s : s + len(cols)] = vals[: len(cols)]
+            else:
+                # Candidate-eval bin bound: full static cap·V for device-capable
+                # backends (bit parity with engine="device"), a §5.3 rung when
+                # the ladder is on (matching the device engine's switch), pow2
+                # for the host-only Pallas backends.
+                eval_bins = _eval_bins_for(k)
+                if backend in SWEEP_BACKENDS:
+                    runner = _eval_chunk_sweep(delta, backend, eval_bins, m, v,
+                                               selector)
+                    table = x_t_full
+                else:
+                    runner = _eval_chunk_incremental(delta, backend, eval_bins,
+                                                     m, v, selector)
+                    table = gran.x
+                for s in range(0, len(remaining), nc):
+                    cols = np.asarray(remaining[s : s + nc], np.int32)
+                    pad = nc - len(cols)
+                    padded = np.concatenate([cols, np.full((pad,), cols[-1], np.int32)])
+                    vals = np.asarray(
+                        runner(r_ids, jnp.asarray(padded), table, gran.d, gran.w, active, n, pr_correction)
+                    )
+                    thetas[s : s + len(cols)] = vals[: len(cols)]
+            n_evals += len(remaining)
+
+            best = measures.argmin_with_ties(thetas, tie_tol)  # paper line 13: argmin Θ
+            a_opt = remaining[best]
+
+            r_ids, k_new, theta_active, g_pure = adv(r_ids, gran.x[:, a_opt], gran.d, gran.w, active, n)
+            k = int(k_new)
+            theta_r = float(np.float32(theta_active) + pr_correction)
+            reduct.append(a_opt)
+            remaining.remove(a_opt)
+            theta_hist.append(theta_r)
+            if shrink:
+                _shrink_step(g_pure)
+            iterations += 1
+            per_iter_s.append(time.perf_counter() - it0)
+
+        root.set(k=len(reduct))
         return ReductionResult(
             reduct=reduct,
             core=core,
             theta_full=theta_full,
             theta_history=theta_hist,
             iterations=iterations,
-            n_evaluations=n_evals + ev,
+            n_evaluations=n_evals,
             elapsed_s=time.perf_counter() - t0,
-            per_iteration_s=per_iter,
+            per_iteration_s=per_iter_s,
         )
-
-    # --- greedy loop state (engine == "host": the legacy escape hatch) ---
-    r_ids = jnp.zeros((cap,), jnp.int32)
-    k = 1
-    active = gran.valid
-    # float32 accumulation, mirroring the device engine bit-for-bit (so the
-    # two engines' theta histories are byte-identical, asserted in tests)
-    pr_correction = np.float32(0.0)
-    reduct: List[int] = []
-    theta_hist: List[float] = []
-    per_iter_s: List[float] = []
-
-    v = gran.v_max
-
-    # The advance (and, ladder off, the evaluation) uses the engine's static
-    # bin bound cap·V: one compile for the whole run (no power-of-two
-    # recompile ladder) and Θ summed over the same padded rows as
-    # engine="device" — zero rows add exactly 0 in f32, but reduction
-    # *grouping* depends on length, so equal lengths ⇒ equal bits (candidate
-    # thetas AND recorded histories).  The §5.3 ladder shrinks only the
-    # *candidate evaluation* bins; the advance keeps the full bound, which is
-    # what keeps theta histories byte-identical across every (backend,
-    # ladder) combination.
-    adv = _make_advance(cap * v, v, m, delta)
-
-    # K-adaptive candidate-eval bins (ladder on): the host twin of the
-    # engine's lax.switch — same static rung set, chosen per iteration from
-    # the synced k, one (lru-cached) compile per rung actually visited.
-    # The selector-pruned set is a function of (cap, m) only, so host and
-    # device engines derive identical rungs (byte parity, DESIGN.md §5.3).
-    rungs = ladder_rungs(cap * v, selector=selector, g=cap, m=m)
-
-    def _eval_bins_for(k_):
-        if ladder:
-            return rung_for(k_, v, rungs)
-        # device-capable backends pin the full static bound for bit parity
-        # with engine="device"; host-only Pallas backends keep the cheaper
-        # pow2 ladder (no device twin to match)
-        return cap * v if backend in DEVICE_BACKENDS else _next_pow2(max(k_, 1)) * v
-
-    # read-once candidate slab for the sweep backends, hoisted out of the
-    # loop (the device engine hoists the same transpose before its while_loop)
-    x_t_full = jnp.swapaxes(gran.x, 0, 1) if backend in SWEEP_BACKENDS else None
-
-    # The stop threshold mirrors the device cond's f32 arithmetic exactly, so
-    # both engines run the same number of iterations even when theta_r lands
-    # within an ulp of it.
-    stop_thresh = measures.f32_threshold(theta_full, tol)
-
-    def _shrink_step(g_pure):
-        nonlocal pr_correction, active
-        if delta == "PR":
-            shed = jnp.sum(jnp.where(g_pure, gran.w, 0)).astype(jnp.float32)
-            pr_correction = pr_correction - np.float32(shed / jnp.float32(n))
-        active = active & ~g_pure
-
-    # fold the forced prefix (core attributes, or the warm-start prefix)
-    for a in forced:
-        r_ids, k_new, theta_r, g_pure = adv(r_ids, gran.x[:, a], gran.d, gran.w, active, n)
-        k = int(k_new)
-        reduct.append(a)
-        theta_hist.append(float(np.float32(theta_r) + pr_correction))
-        if shrink:
-            _shrink_step(g_pure)
-
-    theta_r = theta_hist[-1] if theta_hist else float("inf")
-
-    remaining = [a for a in range(A) if a not in reduct]
-    iterations = 0
-    while remaining and theta_r > stop_thresh:
-        if max_features is not None and len(reduct) >= max_features:
-            break
-        it0 = time.perf_counter()
-        nc = min(mp_chunk, max(len(remaining), 1))
-
-        thetas = np.full((len(remaining),), np.inf, np.float64)
-        if mode == "spark":
-            # re-key from scratch: fingerprint of current R columns
-            if reduct:
-                hR1 = sum_terms(gran.x, reduct, 0)
-                hR2 = sum_terms(gran.x, reduct, 7919)
-            else:
-                hR1 = jnp.zeros((cap,), jnp.uint32)
-                hR2 = jnp.zeros((cap,), jnp.uint32)
-            runner = _eval_chunk_spark(delta, cap, m, v)
-            for s in range(0, len(remaining), nc):
-                cols = np.asarray(remaining[s : s + nc], np.int32)
-                pad = nc - len(cols)
-                padded = np.concatenate([cols, np.full((pad,), cols[-1], np.int32)])
-                vals = np.asarray(
-                    runner(hR1, hR2, jnp.asarray(padded), gran.x, gran.d, gran.w, active, n, pr_correction)
-                )
-                thetas[s : s + len(cols)] = vals[: len(cols)]
-        else:
-            # Candidate-eval bin bound: full static cap·V for device-capable
-            # backends (bit parity with engine="device"), a §5.3 rung when
-            # the ladder is on (matching the device engine's switch), pow2
-            # for the host-only Pallas backends.
-            eval_bins = _eval_bins_for(k)
-            if backend in SWEEP_BACKENDS:
-                runner = _eval_chunk_sweep(delta, backend, eval_bins, m, v,
-                                           selector)
-                table = x_t_full
-            else:
-                runner = _eval_chunk_incremental(delta, backend, eval_bins,
-                                                 m, v, selector)
-                table = gran.x
-            for s in range(0, len(remaining), nc):
-                cols = np.asarray(remaining[s : s + nc], np.int32)
-                pad = nc - len(cols)
-                padded = np.concatenate([cols, np.full((pad,), cols[-1], np.int32)])
-                vals = np.asarray(
-                    runner(r_ids, jnp.asarray(padded), table, gran.d, gran.w, active, n, pr_correction)
-                )
-                thetas[s : s + len(cols)] = vals[: len(cols)]
-        n_evals += len(remaining)
-
-        best = measures.argmin_with_ties(thetas, tie_tol)  # paper line 13: argmin Θ
-        a_opt = remaining[best]
-
-        r_ids, k_new, theta_active, g_pure = adv(r_ids, gran.x[:, a_opt], gran.d, gran.w, active, n)
-        k = int(k_new)
-        theta_r = float(np.float32(theta_active) + pr_correction)
-        reduct.append(a_opt)
-        remaining.remove(a_opt)
-        theta_hist.append(theta_r)
-        if shrink:
-            _shrink_step(g_pure)
-        iterations += 1
-        per_iter_s.append(time.perf_counter() - it0)
-
-    return ReductionResult(
-        reduct=reduct,
-        core=core,
-        theta_full=theta_full,
-        theta_history=theta_hist,
-        iterations=iterations,
-        n_evaluations=n_evals,
-        elapsed_s=time.perf_counter() - t0,
-        per_iteration_s=per_iter_s,
-    )
 
 
 # ---------------------------------------------------------------------------

@@ -2,8 +2,9 @@
 
 Two small host-side pieces, imported by every instrumented subsystem
 (``repro.obs`` deliberately imports nothing from the rest of the repo, and
-no JAX — it must be safe to call from any layer, including module import
-time):
+no JAX at import — it must be safe to call from any layer, including module
+import time; enabled tracing imports JAX to mark each span on the
+``jax.profiler`` trace):
 
 * :mod:`repro.obs.trace` — the span API and bounded ring buffer (flight
   recorder) with Perfetto/Chrome-trace export and dump-on-failure.

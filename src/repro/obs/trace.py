@@ -22,6 +22,13 @@ Design constraints, in priority order:
 * **Host-side only.**  Spans wrap dispatches (``block_until_ready`` and
   friends), never traced/jitted code: a span inside a ``lax.while_loop``
   body would either break tracing or record trace-time, not run-time.
+* **One clock with the device.**  While enabled, a live span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name (and an event, an instant
+  one), so under a ``jax.profiler`` trace every span shows on the host
+  track beside the device ops, on the profiler's clock.  The annotation
+  adds ~0.6–0.8 µs to a live span (2.6–2.9 µs against 2.0–2.1 µs without
+  it, on a TPU v5e host, with or without a profiler session).  JAX is
+  imported at ``enable()`` or at the first live span, never at import.
 * **Bounded.**  The ring buffer holds the last ``capacity`` records
   (default 65536); a week-long serving process keeps its most recent
   history and nothing else.  ``dump()`` serializes that tail next to the
@@ -111,6 +118,29 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# ``jax.profiler.TraceAnnotation``, resolved at the first need (the module
+# imports no JAX); ``False`` where JAX is not installed.
+_ANNOTATION: Any = None
+
+
+def _annotation_cls():
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+def _annotate_instant(name: str) -> None:
+    """A zero-length profiler annotation: the event's mark on the trace."""
+    cls = _annotation_cls()
+    if cls:
+        with cls(name):
+            pass
+
 
 class _LiveSpan:
     """An open span: closes (and records) on ``__exit__``.
@@ -119,7 +149,7 @@ class _LiveSpan:
     dispatch hit a fresh compile is only known once it returns.
     """
 
-    __slots__ = ("_tracer", "name", "_attrs", "_t0")
+    __slots__ = ("_tracer", "name", "_attrs", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  attrs: Optional[Dict[str, Any]]):
@@ -127,6 +157,7 @@ class _LiveSpan:
         self.name = name
         self._attrs = attrs
         self._t0 = 0.0
+        self._annotation = None
 
     def set(self, **attrs) -> "_LiveSpan":
         if self._attrs is None:
@@ -136,11 +167,18 @@ class _LiveSpan:
         return self
 
     def __enter__(self) -> "_LiveSpan":
+        cls = _annotation_cls()
+        if cls:
+            self._annotation = cls(self.name)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if exc_type is not None:
             self.set(error=exc_type.__name__)
         self._tracer._record(
@@ -181,6 +219,7 @@ class Tracer:
             with self._lock:
                 self._buf = collections.deque(self._buf,
                                               maxlen=max(int(capacity), 1))
+        _annotation_cls()
         self.enabled = True
         return self
 
@@ -214,6 +253,7 @@ class Tracer:
         """Instant record (retry fired, fault injected, quarantine, ...)."""
         if not self.enabled:
             return
+        _annotate_instant(name)
         self._record(name, "i", time.perf_counter(), 0.0, attrs or None)
 
     def _record(self, name: str, ph: str, t0: float, dur: float,
@@ -315,6 +355,7 @@ def span(name: str, **attrs):
 
 def event(name: str, **attrs) -> None:
     if _TRACER.enabled:
+        _annotate_instant(name)
         _TRACER._record(name, "i", time.perf_counter(), 0.0, attrs or None)
 
 
