@@ -71,6 +71,7 @@ from .engine import (
 from .granularity import (
     Granularity,
     build_granularity,
+    finish_fold,
     fold_chunk,
     next_pow2,
     with_capacity,
@@ -358,6 +359,7 @@ def _granularity_from_source(source, mesh: Mesh, *, n_dec: int, v_max: int,
             accs[s] = fold_chunk(
                 accs[s], jax.device_put(xc[lo:hi], devs[s]),
                 jax.device_put(dc[lo:hi], devs[s]), n_dec=n_dec, v_max=v_max)
+    accs = [finish_fold(g) for g in accs]
     if any(g is None for g in accs):
         raise ValueError("source yielded no rows for at least one data shard")
     cap_ps = max(next_pow2(max(int(g.num), 16)) for g in accs)

@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -34,9 +34,11 @@ from repro import obs
 
 __all__ = [
     "Granularity",
+    "PendingFold",
     "build_granularity",
     "build_granularity_streaming",
     "fold_chunk",
+    "finish_fold",
     "merge_granularity",
     "with_capacity",
     "next_pow2",
@@ -271,19 +273,22 @@ def merge_granularity(a: Granularity, b: Granularity, *, exact: bool = True,
     """Monoid merge: ``G^(A∪B) = G^(A) ⊕ G^(B)`` — the chunked reduceByKey.
 
     Concatenates the two padded tables and re-granulates with the input
-    weights (concat → sort → adjacent-compare → ``segment_sum``), so
-    duplicate keys across the operands merge weight-additively.  The merge is
-    associative and commutative up to padding: the output's live prefix is
-    the *globally sorted* distinct-key table, independent of operand order
-    or how rows were split between operands.
+    weights (concat → exact class ids → ``segment_sum``), so duplicate keys
+    across the operands merge weight-additively.  The merge is associative
+    and commutative up to padding: the output's live prefix is the
+    *globally sorted* distinct-key table, independent of operand order or
+    how rows were split between operands.  The operands need not be
+    front-packed or distinct-keyed: only their validity masks and weights
+    are read.
 
-    Capacity-doubling policy: the result capacity starts at
-    ``next_pow2(max(capacity or 0, a.capacity, b.capacity))`` and doubles
-    (via ``next_pow2`` of the true distinct count) whenever the live keys
-    overflow it.  The overflow check is one host sync of ``num`` — ``num``
-    counts sort boundaries *before* the scatter clips, so a clipped build is
-    always detected and rebuilt; capacities stay powers of two so the
-    streaming fold compiles O(log G) variants, not one per merge.
+    Capacity policy: the result capacity starts at
+    ``next_pow2(max(capacity or 0, a.capacity, b.capacity))``.  If the live
+    keys overflow it, the merge is built again at ``next_pow2`` of the true
+    distinct count (``plar_merge_rebuilds_total``) — ``num`` counts keys
+    *before* the scatter clips, so a clipped build is always detected.  A
+    caller that passes ``capacity ≥ a.capacity + b.capacity``, as the
+    streaming fold's flush does, can never overflow.  Capacities stay
+    powers of two, so a fold compiles O(log G) variants, not one per merge.
     """
     if (a.n_attrs, a.n_dec, a.v_max) != (b.n_attrs, b.n_dec, b.v_max):
         raise ValueError(
@@ -308,6 +313,45 @@ def merge_granularity(a: Granularity, b: Granularity, *, exact: bool = True,
         cap = next_pow2(num)
 
 
+@dataclasses.dataclass(frozen=True)
+class PendingFold:
+    """A streaming fold between chunks: ``acc``, the merged accumulator, and
+    ``run``, the chunk granule tables appended since and not yet merged into
+    it.  :func:`fold_chunk` returns one while its run is short of the
+    accumulator's capacity; :func:`finish_fold` merges what is pending."""
+
+    acc: Granularity
+    run: Tuple[Granularity, ...]
+    exact: bool
+    seed: int
+
+    def flush(self) -> Granularity:
+        """Merge the run into the accumulator in one merge (an
+        ``ingest.merge`` span) and shrink the result to ``next_pow2(num)``.
+
+        The run is concatenated and padded to a power of two, so the merge
+        builds at ``acc.capacity + run.capacity`` rows, a power of two
+        where the two match; merging at ``next_pow2`` of that capacity can
+        never overflow, so the merge is never built twice."""
+        with obs.span("ingest.merge", chunks=len(self.run)) as msp:
+            # one table of the run's rows: arrays concatenated, counts summed
+            run = self.run[0] if len(self.run) == 1 else jax.tree.map(
+                lambda *v: jnp.concatenate(v) if v[0].ndim else sum(v),
+                *self.run)
+            run = with_capacity(run, next_pow2(run.capacity))
+            acc = merge_granularity(
+                self.acc, run, exact=self.exact, seed=self.seed,
+                capacity=next_pow2(self.acc.capacity + run.capacity))
+            num = int(acc.num)
+            acc = with_capacity(acc, next_pow2(max(num, 1)))
+            msp.set(capacity=acc.capacity, granules=num)
+        obs.counter("plar_fold_deferred_chunks_total",
+                    "chunk tables a streaming fold held in a run instead of "
+                    "merging them at once (merges saved)").inc(
+                        len(self.run) - 1)
+        return acc
+
+
 def build_granularity_streaming(
     chunks,
     *,
@@ -316,37 +360,54 @@ def build_granularity_streaming(
     exact: bool = True,
     seed: int = 0,
 ) -> Granularity:
-    """GrC initialization without the whole table: fold :func:`merge_granularity`
-    over an iterable of ``(x, d)`` row chunks.
+    """GrC initialization without the whole table: fold :func:`fold_chunk`
+    over an iterable of ``(x, d)`` row chunks, then :func:`finish_fold`.
 
-    Each chunk is granulated at its own ``next_pow2`` capacity and merged
-    into the accumulator, so peak memory is O(chunk + accumulator capacity)
-    — the decision table never exists whole.  Because the merge is a monoid
-    and the final fold step re-sorts the full distinct-key set, the live
-    prefix of the result is *element-wise identical* to a monolithic
-    :func:`build_granularity` over the concatenated rows (only the padded
-    capacity may differ); `tests/test_streaming.py` asserts this per
-    chunk size.
+    Each chunk is granulated at its own ``next_pow2`` capacity and folded
+    into the accumulator on :func:`fold_chunk`'s cadence, so peak memory is
+    O(chunk + accumulator capacity) — the decision table never exists
+    whole.  Because the merge is a monoid and every merge re-sorts the
+    full distinct-key set, the live prefix of the result is *element-wise
+    identical* to a monolithic :func:`build_granularity` over the
+    concatenated rows, and its capacity is ``next_pow2(num)``;
+    `tests/test_streaming.py` asserts this per chunk size.
     """
-    acc: Optional[Granularity] = None
+    acc = None
     for xc, dc in chunks:
         acc = fold_chunk(acc, xc, dc, n_dec=n_dec, v_max=v_max, exact=exact,
                          seed=seed)
+    acc = finish_fold(acc)
     if acc is None:
         raise ValueError("build_granularity_streaming: no non-empty chunks")
     return acc
 
 
-def fold_chunk(acc: Optional[Granularity], xc, dc, *, n_dec: int, v_max: int,
-               exact: bool = True, seed: int = 0) -> Optional[Granularity]:
-    """One step of the streaming fold: granulate a row chunk and merge it.
+def fold_chunk(acc: Union[None, Granularity, PendingFold], xc, dc, *,
+               n_dec: int, v_max: int, exact: bool = True,
+               seed: int = 0) -> Union[None, Granularity, PendingFold]:
+    """One step of the streaming fold: granulate a row chunk and append it
+    to the accumulator's run, merging the run once it is an accumulator's
+    worth.
 
-    The single home of the capacity/shrink policy, shared by the
-    single-process and per-data-shard (``distributed``) folds: both operands
-    shrink to their live counts before the merge — on redundant tables a
-    chunk's granularity is far smaller than the chunk, and the merge sort
-    should pay for live keys, not padding.  The host syncs are the per-merge
-    count() the policy already requires.
+    The single home of the capacity/shrink/cadence policy, shared by the
+    single-process, per-data-shard (``distributed``, ``recovery``) and
+    online-update (``service.state``) folds.  The chunk's table shrinks to
+    ``next_pow2`` of its live count: on redundant tables a chunk's
+    granularity is far smaller than the chunk, and a merge should pay for
+    live keys, not padding.  The first chunk's table becomes the
+    accumulator.  Each later one joins a run of pending tables; once the
+    run's capacity reaches the accumulator's, :meth:`PendingFold.flush`
+    merges the whole run in one merge (an ``ingest.merge`` span inside
+    this chunk's ``pipeline.fold_chunk``).  A merge re-groups the
+    accumulator and the run whole, so merging per accumulator's worth of
+    chunks, not per chunk, keeps the re-grouped rows within about twice
+    the chunk tables' rows.  Nothing is tunable: the cadence follows the
+    capacities alone, and a fold whose accumulator is no larger than a
+    chunk's table merges every chunk.
+
+    Returns a :class:`Granularity` where nothing is pending, else a
+    :class:`PendingFold`; pass it to the next call, and read the fold
+    through :func:`finish_fold`.  An empty chunk returns ``acc`` itself.
 
     ``ingest.h2d`` times the host's issue of the chunk's copies, not the
     transfer: that ends under ``ingest.granulate``, whose build waits for
@@ -370,13 +431,28 @@ def fold_chunk(acc: Optional[Granularity], xc, dc, *, n_dec: int, v_max: int,
         if acc is None:
             sp.set(granules=int(g.num))
             return g
-        with obs.span("ingest.merge") as msp:
-            acc = merge_granularity(acc, g, exact=exact, seed=seed)
-            num = int(acc.num)
-            acc = with_capacity(acc, next_pow2(max(num, 1)))
-            msp.set(capacity=acc.capacity, granules=num)
-        sp.set(granules=num)
+        if isinstance(acc, Granularity):
+            acc = PendingFold(acc, (), exact, seed)
+        acc = dataclasses.replace(acc, run=acc.run + (g,))
+        if sum(t.capacity for t in acc.run) < acc.acc.capacity:
+            return acc
+        acc = acc.flush()
+        sp.set(granules=int(acc.num))
     return acc
+
+
+def finish_fold(acc: Union[None, Granularity, PendingFold]
+                ) -> Optional[Granularity]:
+    """The fold's granularity: ``acc`` with its pending run merged, in a
+    ``pipeline.fold_chunk`` span of its own (no rows), so the fold's spans
+    still hold all of its work.  A :class:`Granularity` or ``None`` is
+    returned as it is."""
+    if not isinstance(acc, PendingFold):
+        return acc
+    with obs.span("pipeline.fold_chunk", rows=0, fresh=False) as sp:
+        g = acc.flush()
+        sp.set(granules=int(g.num))
+    return g
 
 
 def regranulate(gran: Granularity, cols: jnp.ndarray, *, exact: bool = True, seed: int = 0) -> Granularity:

@@ -36,6 +36,7 @@ from repro import obs
 
 from .granularity import (
     Granularity,
+    finish_fold,
     fold_chunk,
     merge_granularity,
     next_pow2,
@@ -189,6 +190,7 @@ def _build_folds(source, n_shards: int, chunk_rows: int, exact: bool,
                 accs[s] = fold_chunk(accs[s], xc[lo:hi], dc[lo:hi],
                                      n_dec=source.n_dec, v_max=source.v_max,
                                      exact=exact)
+    accs[:] = [finish_fold(g) for g in accs]
 
 
 def refold_shard(source, lineage: ShardLineage) -> Granularity:
@@ -204,6 +206,7 @@ def refold_shard(source, lineage: ShardLineage) -> Granularity:
             acc = fold_chunk(acc, xc[sl.lo:sl.hi], dc[sl.lo:sl.hi],
                              n_dec=lineage.n_dec, v_max=lineage.v_max,
                              exact=lineage.exact)
+        acc = finish_fold(acc)
     obs.counter("plar_recovery_refolds_total",
                 "shard lineages replayed by refold_shard").inc()
     if acc is None:

@@ -37,7 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.granularity import Granularity, fold_chunk, row_fingerprints
+from repro.core.granularity import (
+    Granularity, finish_fold, fold_chunk, row_fingerprints)
 from repro.core.measures import f32_threshold
 from repro.core.recovery import ShardLineage, ShardedBuild, build_sharded, recover
 from repro.core.reduction import (
@@ -362,10 +363,13 @@ class DatasetHandle:
         Capacity follows the §3.6 pow2 policy (``fold_chunk``), so the
         engine's static ``n_bins = cap·v_max`` — and therefore its compile —
         only changes when the live granule count crosses a power of two.
+        The fold is finished at once (``finish_fold``): the batch is served
+        from the next query on.
         """
         x, d = self.validate_batch(x, d)
-        folded = fold_chunk(self.gran, x, d, n_dec=self.gran.n_dec,
-                            v_max=self.gran.v_max, exact=self.exact)
+        folded = finish_fold(fold_chunk(
+            self.gran, x, d, n_dec=self.gran.n_dec, v_max=self.gran.v_max,
+            exact=self.exact))
         if folded is not self.gran:  # empty batches are identity
             self.gran = folded
             self._fp = None

@@ -3,8 +3,8 @@ place on the ``jax.profiler`` clock.
 
 A streamed reduction records, per chunk, its host→device copy
 (``ingest.h2d``) beside ``pipeline.fold_chunk``, which holds the chunk's
-grouping (``ingest.granulate``) and its merge into the accumulator
-(``ingest.merge``); then Θ(D|C), the core and the engine's dispatch, all
+grouping (``ingest.granulate``) and, where the chunk fills the pending run,
+the run's merge into the accumulator (``ingest.merge``); then Θ(D|C), the core and the engine's dispatch, all
 inside one ``reduction.plar_reduce`` root.  The benchmark's per-layer
 metrics read these names, so a rename fails here and not silently there.
 Tracing off records nothing and leaves the reduct as it was.
@@ -22,7 +22,8 @@ import pytest
 from repro import obs
 from repro.core import plar_reduce, resolve_granularity
 from repro.core.engine import _forced_attrs, init_state, make_engine_run
-from repro.core.granularity import exact_class_ids
+from repro.core.granularity import (
+    build_granularity, exact_class_ids, merge_granularity)
 
 ROOT = Path(__file__).resolve().parents[1]
 CHUNK = 256
@@ -85,7 +86,8 @@ def test_streamed_reduction_spans_nest_under_the_root(tracer):
     assert all(inside(r, root) for r in recs)
     n_chunks = src.n_chunks(CHUNK)
     h2d = named(recs, "ingest.h2d")
-    folds = named(recs, "pipeline.fold_chunk")
+    every_fold = named(recs, "pipeline.fold_chunk")
+    folds = [f for f in every_fold if f.args["rows"]]
     assert len(h2d) == len(folds) == n_chunks
     for copy, fold in zip(h2d, folds):
         # the copy precedes its fold, outside it
@@ -93,17 +95,33 @@ def test_streamed_reduction_spans_nest_under_the_root(tracer):
         assert copy.args["rows"] == fold.args["rows"]
         assert copy.args["bytes"] == copy.args["rows"] * (5 + 1) * 4
     granulate = named(recs, "ingest.granulate")
+    assert len(granulate) == n_chunks
+    # a merge per flush, inside the fold of the chunk that filled the run
+    # to the accumulator's capacity, or in the fold's closing span
     merge = named(recs, "ingest.merge")
-    assert len(granulate) == n_chunks and len(merge) == n_chunks - 1
-    for i, fold in enumerate(folds):
-        assert inside(granulate[i], fold)
-        if i:
-            assert inside(merge[i - 1], fold)
-            assert merge[i - 1].args["granules"] == fold.args["granules"]
+    acc_cap, run = granulate[0].args["capacity"], []
+    flushes = iter(merge)
+    for fold, gran in zip(folds, granulate):
+        assert inside(gran, fold)
+        if fold is folds[0]:
+            continue
+        run.append(gran.args["capacity"])
+        if sum(run) >= acc_cap:
+            m = next(flushes)
+            assert inside(m, fold) and m.args["chunks"] == len(run)
+            assert m.args["granules"] == fold.args["granules"]
+            acc_cap, run = m.args["capacity"], []
+    if run:
+        (closing,) = [f for f in every_fold if not f.args["rows"]]
+        m = next(flushes)
+        assert inside(m, closing) and m.args["chunks"] == len(run)
+    assert next(flushes, None) is None
+    assert len(merge) < n_chunks - 1
+    assert sum(m.args["chunks"] for m in merge) == n_chunks - 1
     (theta,) = named(recs, "reduction.theta_full")
     (core,) = named(recs, "reduction.core")
     (engine,) = named(recs, "engine.dispatch")
-    assert folds[-1].t_start + folds[-1].dur <= theta.t_start
+    assert every_fold[-1].t_start + every_fold[-1].dur <= theta.t_start
     assert theta.t_start + theta.dur <= core.t_start
     assert core.t_start + core.dur <= engine.t_start
     assert core.args == {"A": 5, "path": "exact"}
@@ -114,15 +132,26 @@ def test_streamed_reduction_spans_nest_under_the_root(tracer):
 
 def test_a_merge_that_overflows_records_its_rebuild(tracer):
     """A merge whose granules overflow its capacity is built again; the
-    process registry counts it, traced or not, for ``/metrics``."""
+    process registry counts it, traced or not, for ``/metrics``.  A
+    streamed fold merges at a capacity that holds both operands, so it
+    rebuilds nothing."""
     rebuilds = obs.counter("plar_merge_rebuilds_total")
+    rows = np.arange(128, dtype=np.int32)
+    x = np.stack([rows // 16, rows % 16], axis=1)
+    halves = [build_granularity(jnp.asarray(x[h]), jnp.zeros((64,), jnp.int32),
+                                n_dec=1, v_max=16)
+              for h in (slice(0, 64), slice(64, 128))]
     for traced in (True, False):
         tracer.enabled = traced
         before = rebuilds.value
-        plar_reduce(source=table(), chunk_rows=CHUNK, delta="SCE")
-        assert rebuilds.value > before
+        merged = merge_granularity(*halves)
+        assert int(merged.num) == merged.capacity == 128
+        assert rebuilds.value == before + 1
         assert f"plar_merge_rebuilds_total {rebuilds.value}" in \
             obs.render_prometheus().splitlines()
+        before = rebuilds.value
+        plar_reduce(source=table(), chunk_rows=CHUNK, delta="SCE")
+        assert rebuilds.value == before
 
 
 @pytest.mark.parametrize("kind", ["granules", "arrays"])
@@ -190,9 +219,9 @@ def test_every_span_is_on_the_profiler_clock(tracer, tmp_path):
         marks = by_name.get(name, [])
         assert len(marks) == len(ring), name
         offsets += [m - r * 1e9 for m, r in zip(marks, ring)]
-    # per chunk a copy, a fold and a grouping, a merge for all but the
-    # first; then Θ(D|C), the core, the engine and the root
-    assert len(offsets) == len(recs) == 4 * src.n_chunks(CHUNK) + 3
+    # per chunk a copy, a fold and a grouping; two merges (of 1 and of 2
+    # chunks' tables); then Θ(D|C), the core, the engine and the root
+    assert len(offsets) == len(recs) == 3 * src.n_chunks(CHUNK) + 2 + 4
     assert max(offsets) - min(offsets) < 1e6       # 1 ms, in ns
 
 

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 
 from _hyp import given, settings, st  # optional-hypothesis shim: property tests skip on bare envs
 
-from repro.core import build_granularity, fold_chunk, merge_granularity
+from repro.core import (
+    build_granularity, finish_fold, fold_chunk, merge_granularity)
 from repro.service import DatasetHandle, granularity_fingerprint
 
 
@@ -70,7 +71,7 @@ def _check_merge_monoid(n, a, vmax, m, cut1, cut2, seed):
     acc = None
     for xc, dc in parts:
         acc = fold_chunk(acc, jnp.asarray(xc), jnp.asarray(dc), **kw)
-    _assert_same_content(acc, mono)
+    _assert_same_content(finish_fold(acc), mono)
 
     gs = [build_granularity(jnp.asarray(xc), jnp.asarray(dc), **kw)
           for xc, dc in parts if len(xc)]

@@ -14,7 +14,13 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core import plar_reduce
+from repro.core import (
+    build_granularity,
+    merge_granularity,
+    next_pow2,
+    plar_reduce,
+    with_capacity,
+)
 from repro.core.recovery import (
     ChunkSlice,
     ShardLineage,
@@ -74,6 +80,77 @@ def test_refold_shard_bitwise_identical():
         lin = build.lineages[s]
         assert lin.shard_index == s and lin.slices
         _gran_equal(refold_shard(src, lin), build.shards[s])
+
+
+def _merge_every_chunk(chunks, n_dec, v_max):
+    """The fold merging each chunk's table into the accumulator on arrival,
+    the cadence before runs of chunk tables were merged together."""
+    acc = None
+    for xc, dc in chunks:
+        g = build_granularity(jnp.asarray(xc), jnp.asarray(dc), n_dec=n_dec,
+                              v_max=v_max, capacity=next_pow2(len(xc)))
+        g = with_capacity(g, next_pow2(int(g.num)))
+        if acc is not None:
+            g = merge_granularity(acc, g)
+            g = with_capacity(g, next_pow2(int(g.num)))
+        acc = g
+    return acc
+
+
+def _assert_bitwise(a, b):
+    assert a.capacity == b.capacity
+    for field in ("x", "d", "w", "valid", "num", "n_total"):
+        np.testing.assert_array_equal(np.asarray(getattr(a, field)),
+                                      np.asarray(getattr(b, field)), field)
+
+
+def test_shard_folds_match_a_merge_per_chunk():
+    """Each shard's fold, built and re-folded from its lineage, holds runs
+    of chunk slices before it merges them, and its granules are bitwise
+    those of a fold that merges every slice on arrival."""
+    from repro import obs
+
+    src = TabularStream(n_rows=2000, n_attrs=6, v_max=3, n_dec=2,
+                        distinct_fraction=0.3, seed=4)
+    deferred = obs.counter("plar_fold_deferred_chunks_total")
+    before = deferred.value
+    build = build_sharded(src, 2, chunk_rows=64)
+    assert deferred.value > before
+    for lin, shard in zip(build.lineages, build.shards):
+        slices = [src.chunk(sl.step, lin.chunk_rows) for sl in lin.slices]
+        want = _merge_every_chunk(
+            [(xc[sl.lo:sl.hi], dc[sl.lo:sl.hi])
+             for sl, (xc, dc) in zip(lin.slices, slices)],
+            src.n_dec, src.v_max)
+        _assert_bitwise(shard, want)
+        _assert_bitwise(refold_shard(src, lin), want)
+
+
+def test_mesh_shard_fold_matches_a_merge_per_chunk():
+    """The mesh path's per-shard streaming fold (one data shard here)
+    is finished before it is placed: its granules are bitwise those of a
+    fold that merges every chunk on arrival, at capacity next_pow2(num)."""
+    import jax
+
+    from repro import obs
+    from repro.core.distributed import _granularity_from_source
+    from repro.distributed.api import make_mesh
+
+    src = TabularStream(n_rows=2000, n_attrs=6, v_max=3, n_dec=2,
+                        distinct_fraction=0.3, seed=5)
+    mesh = make_mesh((1, 1), ("data", "model"),
+                     devices=np.array(jax.devices()[:1]))
+    deferred = obs.counter("plar_fold_deferred_chunks_total")
+    before = deferred.value
+    gx, gd, gw, gv, n_total = _granularity_from_source(
+        src, mesh, n_dec=src.n_dec, v_max=src.v_max, chunk_rows=64)
+    assert deferred.value > before
+    want = _merge_every_chunk(src.chunks(64), src.n_dec, src.v_max)
+    want = with_capacity(want, max(want.capacity, 16))
+    assert n_total == int(want.n_total)
+    for got, field in zip((gx, gd, gw, gv), ("x", "d", "w", "valid")):
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(getattr(want, field)), field)
 
 
 def test_recover_reproduces_unfailed_build_and_downstream():

@@ -229,6 +229,38 @@ def test_server_coalesces_pending_updates():
     assert r.reduct == full.reduct
 
 
+def test_update_is_served_from_the_next_query():
+    """An update batch far smaller than the dataset's capacity, which a
+    streaming fold would hold pending, is merged at once: the handle's
+    granularity counts its new rows at the next query, in one merge."""
+    from repro import obs
+    from repro.core import Granularity
+
+    rows = np.arange(1024 + 24, dtype=np.int32)
+    x = np.stack([rows // 64, rows // 8 % 8, rows % 8], axis=1)
+    d = (rows % 2).astype(np.int32)
+    deferred = obs.counter("plar_fold_deferred_chunks_total")
+    rebuilds = obs.counter("plar_merge_rebuilds_total")
+
+    async def drive():
+        async with ReductServer() as srv:
+            await srv.submit("u", x[:1024], d[:1024], n_dec=2, v_max=17)
+            await srv.query("u", delta="SCE")
+            before = srv.handle("u").gran
+            counts = deferred.value, rebuilds.value
+            await srv.update("u", x[1024:], d[1024:])
+            await srv.query("u", delta="SCE")
+            return before, srv.handle("u").gran, counts, srv.stats.copy()
+
+    before, after, counts, stats = asyncio.run(drive())
+    assert before.capacity == 1024 and int(before.num) == 1024
+    assert isinstance(after, Granularity)
+    assert int(after.num) == 1024 + 24 and after.capacity == 2048
+    assert int(after.n_total) == len(rows)
+    assert stats["merges"] == 1
+    assert (deferred.value, rebuilds.value) == counts
+
+
 def test_server_result_cache_and_param_keys():
     """Repeat query on unchanged content is a cache hit; params and content
     changes both miss."""

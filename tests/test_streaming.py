@@ -12,11 +12,14 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
 from repro.core import (
     build_granularity,
     build_granularity_streaming,
+    finish_fold,
     fold_chunk,
     merge_granularity,
+    next_pow2,
     plar_reduce,
     fspa_reduce,
     resolve_granularity,
@@ -127,6 +130,67 @@ def test_fold_empty_chunk_is_identity():
     with pytest.raises(ValueError, match="no non-empty chunks"):
         build_granularity_streaming(iter([(empty_x, empty_d)]), n_dec=2,
                                     v_max=3)
+
+
+def _keyed_rows(keys):
+    """Rows whose key ``k`` is the pair ``(k // 16, k % 16)``, class k mod 3."""
+    keys = np.asarray(keys, np.int32)
+    return np.stack([keys // 16, keys % 16], axis=1), keys % 3
+
+
+# (keys, chunk_rows, chunk tables absorbed by each flush).  The run merges
+# once its capacity reaches the accumulator's: an all-distinct table
+# doubles the accumulator, so its runs double too; a table of 64 keys
+# saturates it at 64, four 16-row chunks a flush; an accumulator no larger
+# than a chunk's table merges every chunk; one chunk merges nothing.  The
+# last chunk is short wherever chunk_rows does not divide the rows.
+CADENCE = {
+    "distinct-1-2-4": (np.arange(117), 16, [1, 2, 4]),
+    "saturating-4s": (np.arange(16 * 14 + 3) * 7 % 64, 16, [1, 2, 4, 4, 3]),
+    "every-chunk": (np.arange(100) * 7 % 4, 16, [1] * 6),
+    "one-chunk": (np.arange(40), 64, []),
+}
+
+
+@pytest.mark.parametrize("case", list(CADENCE))
+def test_fold_merges_a_run_per_accumulator_of_chunks(case):
+    """Each flush merges the chunk tables pending since the last one, once
+    their capacity reaches the accumulator's, in a merge that never
+    overflows; the result is the monolithic build's, at capacity
+    ``next_pow2(num)``, and an empty chunk leaves a pending fold as it is."""
+    keys, chunk_rows, flushes = CADENCE[case]
+    x, d = _keyed_rows(keys)
+    kw = dict(n_dec=3, v_max=16)
+    tracer = obs.get_tracer()
+    was = tracer.enabled
+    tracer.enable()
+    tracer.clear()
+    rebuilds = obs.counter("plar_merge_rebuilds_total")
+    deferred = obs.counter("plar_fold_deferred_chunks_total")
+    before = rebuilds.value, deferred.value
+    empty = (np.zeros((0, 2), np.int32), np.zeros((0,), np.int32))
+    try:
+        acc = None
+        for lo in range(0, len(keys), chunk_rows):
+            acc = fold_chunk(acc, x[lo:lo + chunk_rows], d[lo:lo + chunk_rows],
+                             **kw)
+            assert fold_chunk(acc, *empty, **kw) is acc
+        g = finish_fold(acc)
+        recs = [r for r in tracer.records() if r.ph == "X"]
+    finally:
+        tracer.clear()
+        tracer.enabled = was
+    mono = build_granularity(jnp.asarray(x), jnp.asarray(d), **kw)
+    _assert_same_granularity(g, mono)
+    assert g.capacity == next_pow2(int(g.num))
+    merges = [r for r in recs if r.name == "ingest.merge"]
+    assert [m.args["chunks"] for m in merges] == flushes
+    folds = [r for r in recs if r.name == "pipeline.fold_chunk"]
+    for m in merges:
+        assert any(f.t_start <= m.t_start
+                   and m.t_start + m.dur <= f.t_start + f.dur for f in folds)
+    assert rebuilds.value == before[0]
+    assert deferred.value - before[1] == sum(k - 1 for k in flushes)
 
 
 def test_merge_with_self_doubles_weights():
