@@ -232,41 +232,58 @@ def _core_path(exact: bool, n_attrs: int) -> str:
     return "exact" if exact and n_attrs <= 128 else "sketch"
 
 
+@lru_cache(maxsize=None)
+def _sketch_core(delta, n_bins, m, chunk, n_attrs):
+    """Θ(D|C\\{a}) for every a of an ``n_attrs``-column table, from the
+    linear sketch h(C\\{a}) = h(C) − term_a: one program per shape, every
+    attribute in one dispatch (a ``lax.map`` over ⌈A/chunk⌉ chunks of
+    ``chunk`` attributes, each chunk's groupings batched)."""
+
+    @jax.jit
+    def sketch(x, d, w, valid, n_total, h1, h2):
+        def one(col):
+            t1 = dyn_column_terms(x, col, 0)
+            t2 = dyn_column_terms(x, col, 7919)
+            ids, _k = ids_by_sort([h2 - t2, h1 - t1], valid)
+            cont = contingency_from_ids(ids, d, w, valid, n_bins=n_bins, m=m)
+            return measures.evaluate(delta, cont, n_total)
+
+        return jax.lax.map(one, jnp.arange(n_attrs, dtype=jnp.int32),
+                           batch_size=chunk)
+
+    return sketch
+
+
+def _readbacks(path: str):
+    return obs.counter(f"plar_core_readbacks_{path}_total",
+                       f"device-to-host reads of the core's {path} path")
+
+
 def _core_inner_thetas(gran: Granularity, delta: str, *, exact: bool, chunk: int = 64) -> np.ndarray:
     """Θ(D|C\\{a}) for every a ∈ C (paper lines 3–8, the MP'd core step)."""
     A = gran.n_attrs
     cap = gran.capacity
     n_bins = cap  # ≤ G distinct classes always
-    out = np.zeros((A,), np.float64)
+    path = _core_path(exact, A)
 
-    if _core_path(exact, A) == "exact":
+    if path == "exact":
+        out = np.zeros((A,), np.float64)
         for a in range(A):
             cols = jnp.asarray([j for j in range(A) if j != a], jnp.int32)
             ids, _ = subset_ids(gran, cols, exact=True)
             cont = contingency_from_ids(ids, gran.d, gran.w, gran.valid, n_bins=n_bins, m=gran.n_dec)
             out[a] = float(measures.evaluate(delta, cont, gran.n_total))
+        _readbacks(path).inc(A)
         return out
 
     # Linear-sketch path: h(C\{a}) = h(C) - term_a  — O(1) per candidate.
-    h1, h2 = _full_fingerprints(gran.x, gran.valid)
-
-    @jax.jit
-    def chunk_fn(cand_cols):
-        def one(col):
-            t1 = dyn_column_terms(gran.x, col, 0)
-            t2 = dyn_column_terms(gran.x, col, 7919)
-            ids, _k = ids_by_sort([h2 - t2, h1 - t1], gran.valid)
-            cont = contingency_from_ids(ids, gran.d, gran.w, gran.valid, n_bins=n_bins, m=gran.n_dec)
-            return measures.evaluate(delta, cont, gran.n_total)
-
-        return jax.lax.map(one, cand_cols)
-
-    for s in range(0, A, chunk):
-        cols = np.arange(s, min(s + chunk, A), dtype=np.int32)
-        pad = chunk - len(cols)
-        padded = np.concatenate([cols, np.zeros((pad,), np.int32)])
-        vals = np.asarray(chunk_fn(jnp.asarray(padded)))
-        out[s : s + len(cols)] = vals[: len(cols)]
+    with obs.span("core.fingerprints"):
+        h1, h2 = jax.block_until_ready(_full_fingerprints(gran.x, gran.valid))
+    with obs.span("core.sketch", A=A, chunks=-(-A // chunk), capacity=cap):
+        sketch = _sketch_core(delta, n_bins, gran.n_dec, chunk, A)
+        out = np.asarray(sketch(gran.x, gran.d, gran.w, gran.valid,
+                                gran.n_total, h1, h2), np.float64)
+    _readbacks(path).inc()
     return out
 
 

@@ -130,6 +130,49 @@ def test_streamed_reduction_spans_nest_under_the_root(tracer):
                          "A": 5, "capacity": cap, "k": len(result.reduct)}
 
 
+def wide_table():
+    """400 rows over 300 attributes, past the exact core's 128, so the core
+    groups by fingerprint sketch."""
+    rng = np.random.default_rng(1)
+    x = rng.integers(0, 2, size=(400, 300)).astype(np.int32)
+    return Rows(x, ((x[:, 0] + x[:, 1]) % 2).astype(np.int32))
+
+
+@pytest.mark.parametrize("path", ["exact", "sketch"])
+def test_the_core_spans_and_readbacks_of_each_path(tracer, path):
+    """The sketch path records its fingerprints and its one dispatch of A
+    groupings inside ``reduction.core``; each path counts its device-to-host
+    reads in the process registry, traced or not: A on the exact path, one
+    on the sketch."""
+    src = table() if path == "exact" else wide_table()
+    A = src.x.shape[1]
+    other = {"exact": "sketch", "sketch": "exact"}[path]
+    readbacks = obs.counter(f"plar_core_readbacks_{path}_total")
+    others = obs.counter(f"plar_core_readbacks_{other}_total")
+    for traced in (True, False):
+        tracer.enabled = traced
+        tracer.clear()
+        before, before_other = readbacks.value, others.value
+        _, recs = traced_reduce(tracer, src)
+        assert readbacks.value - before == (A if path == "exact" else 1)
+        assert others.value == before_other
+        if not traced:
+            assert recs == []
+            continue
+        (core,) = named(recs, "reduction.core")
+        assert core.args == {"A": A, "path": path}
+        prints, sketch = (named(recs, "core.fingerprints"),
+                          named(recs, "core.sketch"))
+        if path == "exact":
+            assert prints == sketch == []
+            continue
+        (prints,), (sketch,) = prints, sketch
+        assert inside(prints, core) and inside(sketch, core)
+        assert prints.t_start + prints.dur <= sketch.t_start
+        cap = resolve_granularity(source=src, chunk_rows=CHUNK).capacity
+        assert sketch.args == {"A": A, "chunks": -(-A // 64), "capacity": cap}
+
+
 def test_a_merge_that_overflows_records_its_rebuild(tracer):
     """A merge whose granules overflow its capacity is built again; the
     process registry counts it, traced or not, for ``/metrics``.  A
