@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import List, Optional, Sequence
 
 import jax
@@ -50,8 +50,10 @@ from .granularity import (
     column_terms,
     dyn_column_terms,
     compact_ids,
+    ids_from_presence,
     next_pow2,
     pack_ids,
+    presence_bitmap,
     row_fingerprints,
     with_capacity,
 )
@@ -227,9 +229,66 @@ def _make_advance(n_bins, v_max, m, delta):
 
 
 def _core_path(exact: bool, n_attrs: int) -> str:
-    """How :func:`_core_inner_thetas` groups: ``"exact"`` (one exact
-    grouping per attribute) or ``"sketch"`` (fingerprint sorts)."""
+    """How :func:`_core_inner_thetas` groups: ``"exact"`` (prefix and
+    suffix ranks, paired) or ``"sketch"`` (fingerprint sorts)."""
     return "exact" if exact and n_attrs <= 128 else "sketch"
+
+
+@partial(jax.jit, static_argnames=("v_max",))
+def _exact_core_ids(x, valid, v_max):
+    """Exact class ids of C\\{a} for every a, ``([A, cap], K [A])``: row a
+    is bitwise ``exact_class_ids(x[:, C\\{a}], valid, radix=v_max)``.
+
+    The class of a row under C\\{a} is the pair (P_a, S_{a+1}): P_a ranks
+    the prefix (x_0…x_{a−1}) as the left-to-right fold of
+    :func:`exact_class_ids` does, S_{a+1} ranks the suffix
+    (x_{a+1}…x_{A−1}) lexicographically by a right-to-left fold,
+    ``x_j·cap + S_{j+1}`` with x_j the primary key.  Ranking the pair by a
+    two-key sort, P_a primary, numbers the classes as a lexsort on C\\{a}
+    would.  Both scans fold A − 1 columns into ``cap·v_max`` presence bins;
+    the last attribute's ids are P_{A−1} itself.  Nothing here depends on
+    the measure, so the sort (the costly compile) is compiled once a shape.
+    """
+    cap = x.shape[0]
+    n_bins = cap * v_max
+    x_t = x.T
+
+    def ranks(p):
+        return ids_from_presence(presence_bitmap(p, valid, n_bins), p, valid)
+
+    def suffix(s, col):
+        s, _ = ranks(col * cap + s)
+        return s, s
+
+    def prefix(carry, xs):
+        p, _ = carry
+        col, s_next = xs
+        return ranks(p * v_max + col), ids_by_sort([s_next, p], valid)
+
+    zeros = jnp.zeros((cap,), jnp.int32)
+    _, s = jax.lax.scan(suffix, zeros, x_t[1:], reverse=True)
+    init = (zeros, jnp.any(valid).astype(jnp.int32))
+    (p, k), (ids, ks) = jax.lax.scan(prefix, init, (x_t[:-1], s))
+    return (jnp.concatenate([ids, p[None]]),
+            jnp.concatenate([ks, k[None]]))
+
+
+@lru_cache(maxsize=None)
+def _exact_core(delta, n_bins, m):
+    """Θ(D|C\\{a}) for every a from :func:`_exact_core_ids`' ``[A, cap]``
+    ids: one program per measure and shape, a ``lax.map`` of contingency
+    and Θ over the attributes."""
+
+    @jax.jit
+    def core(ids, d, w, valid, n_total):
+        def one(ids_a):
+            cont = contingency_from_ids(ids_a, d, w, valid, n_bins=n_bins,
+                                        m=m)
+            return measures.evaluate(delta, cont, n_total)
+
+        return jax.lax.map(one, ids)
+
+    return core
 
 
 @lru_cache(maxsize=None)
@@ -267,13 +326,11 @@ def _core_inner_thetas(gran: Granularity, delta: str, *, exact: bool, chunk: int
     path = _core_path(exact, A)
 
     if path == "exact":
-        out = np.zeros((A,), np.float64)
-        for a in range(A):
-            cols = jnp.asarray([j for j in range(A) if j != a], jnp.int32)
-            ids, _ = subset_ids(gran, cols, exact=True)
-            cont = contingency_from_ids(ids, gran.d, gran.w, gran.valid, n_bins=n_bins, m=gran.n_dec)
-            out[a] = float(measures.evaluate(delta, cont, gran.n_total))
-        _readbacks(path).inc(A)
+        ids, _ = _exact_core_ids(gran.x, gran.valid, gran.v_max)
+        core = _exact_core(delta, n_bins, gran.n_dec)
+        out = np.asarray(core(ids, gran.d, gran.w, gran.valid, gran.n_total),
+                         np.float64)
+        _readbacks(path).inc()
         return out
 
     # Linear-sketch path: h(C\{a}) = h(C) - term_a  — O(1) per candidate.

@@ -2,11 +2,12 @@
 
 The TPU compiler refuses what interpret mode accepts: misaligned Pallas
 blocks, scalar stores into VMEM, programs larger than the chip's HBM.
-These tests compile the contingency kernels and the device engine at the
-kdd99 flagship's widths (granule capacity 2^17, 41 attributes, 23 classes,
-v_max 10) for one chip of a described ``v5e:2x2`` topology, so such a
-regression fails here and costs no chip time.  Nothing runs: results are
-covered by the interpret-mode tests and by ``chip_smoke.py`` on the chip.
+These tests compile the contingency kernels, the device engine and the
+exact core at the kdd99 flagship's widths (granule capacity 2^17, 41
+attributes, 23 classes, v_max 10) for one chip of a described ``v5e:2x2``
+topology, so such a regression fails here and costs no chip time.  Nothing
+runs: results are covered by the interpret-mode tests and by
+``chip_smoke.py`` on the chip.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -24,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.engine import SelectionState, make_engine_run
 from repro.core.granularity import build_granularity
 from repro.core.plan import ladder_rungs
+from repro.core.reduction import _exact_core, _exact_core_ids
 from repro.kernels.contingency.fused import fused_theta_pallas
 from repro.kernels.contingency.kernel import contingency_pallas
 from repro.kernels.contingency.model import select_tiles
@@ -141,3 +143,16 @@ def test_grc_merge_compiles(spec):
         v_max=V_MAX, w=spec((n,), jnp.int32), valid=spec((n,), jnp.bool_),
         exact=True, capacity=CAP).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_exact_core_compiles(spec):
+    # the core's pair ids (prefix and suffix scans, a two-key sort a
+    # subset) once a shape, and its contingency and Θ once a measure
+    vec = lambda dtype: spec((CAP,), dtype)
+    ids = _exact_core_ids.lower(spec((CAP, A), jnp.int32), vec(jnp.bool_),
+                                v_max=V_MAX).compile()
+    mem = ids.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.output_size_in_bytes < 2 ** 30
+    _exact_core("SCE", CAP, N_DEC).lower(
+        spec((A, CAP), jnp.int32), vec(jnp.int32), vec(jnp.int32),
+        vec(jnp.bool_), spec((), jnp.int32)).compile()
