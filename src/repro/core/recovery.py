@@ -22,8 +22,7 @@ GrC granularity build (DESIGN.md §3.10):
   histories; tests/test_recovery.py).
 
 Recovery cost model: a shard death costs ``O(rows/S)`` re-fold work plus
-one (S-way) re-merge, versus ``O(rows)`` for a from-scratch rebuild — the
-re-fold-one-shard ≪ full-rebuild gap measured in benchmarks/chaos_bench.py.
+one (S-way) re-merge, versus ``O(rows)`` for a from-scratch rebuild.
 """
 from __future__ import annotations
 

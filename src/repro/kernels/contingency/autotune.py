@@ -14,7 +14,7 @@ Three selector modes, shared by every kernel entry point
   largest MXU-aligned tile under the VMEM budget.  Kept as the legacy
   fallback and as a parity baseline for the selector tests.
 * ``pinned`` — the kernels' module defaults (``DEFAULT_BK``/``BG``/``BC``),
-  for pinning a known tiling in benchmarks and bisections.
+  for pinning a known tiling in tests and bisections.
 
 :func:`autotune_block_sizes` is the measured refinement: the analytic rank
 prunes the candidate grid to a top-k (default 3) **before any timing**, and

@@ -1,7 +1,7 @@
 """JAX's persistent compile cache for the entry points.
 
 Each entry point (``chip_smoke.py``, ``launch/reduce.py``,
-``launch/reduce_server.py``, ``benchmarks/run.py``) calls :func:`configure`
+``launch/reduce_server.py``) calls :func:`configure`
 first thing in ``main``.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
 already reads it and nothing is changed; otherwise the cache goes to one
 fixed directory inside the checkout (listed in ``.gitignore``), so a second

@@ -17,7 +17,8 @@ Design constraints, in priority order:
   ``span()`` returns a process-wide singleton no-op context manager — no
   object allocation, no lock, no timestamp read — so instrumentation can
   live permanently in hot paths (asserted by tests/test_obs.py with
-  ``tracemalloc`` and measured in benchmarks/obs_bench.py).  The
+  ``tracemalloc``: ``test_disabled_span_is_the_singleton`` and
+  ``test_disabled_span_allocates_nothing``).  The
   *attribute* kwargs a call site passes are the only per-call cost.
 * **Host-side only.**  Spans wrap dispatches (``block_until_ready`` and
   friends), never traced/jitted code: a span inside a ``lax.while_loop``
@@ -63,8 +64,7 @@ __all__ = [
 ]
 
 # Default ring depth: at ~120 bytes/record this is <10 MB resident, yet
-# covers minutes of a busy serving process (the serve-bench firehose emits
-# ~40 spans/query).
+# covers minutes of a busy serving process (~40 spans a query).
 _DEFAULT_CAPACITY = 65536
 
 # Flight-recorder dumps kept per directory (older ones are GC'd): a fault
@@ -189,7 +189,8 @@ class _LiveSpan:
 def _category(name: str) -> str:
     """Subsystem category = the dotted prefix (``engine.dispatch`` →
     ``engine``): the Perfetto color/filter key and the ≥4-subsystems
-    coverage check of benchmarks/obs_bench.py."""
+    coverage check of tests/test_obs.py
+    (``test_traced_chaos_run_covers_every_subsystem``)."""
     i = name.find(".")
     return name[:i] if i > 0 else name
 

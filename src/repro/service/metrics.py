@@ -2,11 +2,10 @@
 
 Two layers, deliberately small:
 
-* :class:`RequestTiming` — the per-request stamp triple every serving
-  surface in the repo records: enqueue → start (admitted / batch formed) →
-  done.  Both the reduct server's :class:`~repro.service.ReduceRequest`
-  and the LM engine's :class:`~repro.serving.engine.Request` carry one, so
-  "queue wait" and "service time" mean the same thing across subsystems.
+* :class:`RequestTiming` — the reduct server's own per-request stamp
+  triple: enqueue → start (admitted / batch formed) → done.  Every
+  :class:`~repro.service.ReduceRequest` carries one, so "queue wait" and
+  "service time" mean the same thing for every query.
 * :class:`ServiceMetrics` — the aggregate view the multi-tenant scheduler
   feeds: bounded windows of wait/latency samples (p50/p99 without keeping
   every request alive), batch-occupancy accounting per engine dispatch,
@@ -18,8 +17,8 @@ callers provide (the scheduler serializes engine dispatches; merge threads
 touch only counters, which are guarded by the server's cache lock).
 
 Since DESIGN.md §3.11 the counters and latency samples are backed by an
-:class:`repro.obs.MetricsRegistry` — per instance, because tests and
-benchmarks build many servers per process — so the same numbers that feed
+:class:`repro.obs.MetricsRegistry` — per instance, because tests build
+many servers per process — so the same numbers that feed
 ``summary()`` also render on the Prometheus exposition.  ``summary()``
 output is byte-compatible with the pre-registry version.
 """

@@ -2,7 +2,7 @@
 
 The PR 5 worker was single-flight: one queue, one request per engine
 dispatch.  This scheduler replaces it with *cross-query batching* — the
-continuous-batching idiom of ``serving/engine.py`` applied to attribute
+continuous-batching idiom of LM decode servers applied to attribute
 reduction:
 
 * **Window** — when a request is picked up, the queue is drained
@@ -143,8 +143,10 @@ class Scheduler:
     """Drains the server queue in windows and dispatches batched work.
 
     ``batching=False`` degrades to the PR 5 single-flight worker — one
-    request per window, solo dispatch — which is the benchmark baseline
-    (``benchmarks/serve_bench.py``).  ``retry``/``fault_plan``/
+    request per window, solo dispatch — the single-flight twin that
+    tests/test_scheduler.py holds batched answers byte-equal to
+    (``test_concurrent_clients_batched_into_stacked_dispatches``).
+    ``retry``/``fault_plan``/
     ``serve_stale`` are the §3.10 resilience knobs (module docstring).
     """
 

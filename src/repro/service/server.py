@@ -1,8 +1,8 @@
 """Async multi-tenant reduct server: batched dispatch, dedup, admission.
 
-The serving layer of DESIGN.md §3.7/§3.9, shaped like ``serving/engine.py``'s
-Request pattern: requests enter a *bounded* asyncio queue, one scheduler
-task (:class:`~repro.service.scheduler.Scheduler`) drains it in windows,
+The serving layer of DESIGN.md §3.7/§3.9: requests enter a *bounded*
+asyncio queue, one scheduler task
+(:class:`~repro.service.scheduler.Scheduler`) drains it in windows,
 and the expensive JAX work runs in threads so the event loop stays
 responsive for admission, dedup, and rejection.
 
@@ -113,7 +113,7 @@ def _norm_items(params: Dict[str, Any]) -> Tuple[Tuple[str, Any], ...]:
 
 @dataclasses.dataclass
 class ReduceRequest:
-    """One query through the queue (the serving/engine.py Request shape)."""
+    """One query through the queue."""
 
     rid: int
     dataset: str
@@ -123,7 +123,7 @@ class ReduceRequest:
     # ensemble queries: the expanded config grid (sorted-items tuples);
     # None marks a single-config query
     configs: Optional[Tuple[Tuple[Tuple[str, Any], ...], ...]] = None
-    # latency accounting (shared shape with serving/engine.py):
+    # latency accounting:
     timing: RequestTiming = dataclasses.field(default_factory=RequestTiming)
     # filled by the scheduler:
     cached: bool = False

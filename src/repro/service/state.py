@@ -25,8 +25,9 @@ super-reduct (the greedy stopping rule re-checks Θ against the *current*
 Θ(D|C)), but the prefix is kept on significance, not re-checked for
 argmin-optimality — re-checking would cost exactly a full recompute.  On
 incrementally grown tables the greedy prefix is stable and the repaired
-reduct matches the from-scratch one (asserted end-to-end in
-tests/test_service.py; measured in benchmarks/service_bench.py).
+reduct matches the from-scratch one and reaches the stopping target
+(asserted in tests/test_service.py: ``test_handle_reduce_warm_matches_cold``
+and, end-to-end, ``test_service_streaming_matches_batch``).
 """
 from __future__ import annotations
 
@@ -386,7 +387,8 @@ class DatasetHandle:
         """Reduct for the current granularity under ``(delta, params)``.
 
         Warm-repairs from the last result of the same config when one
-        exists (``warm=False`` forces a cold run — the benchmark baseline).
+        exists (``warm=False`` forces a cold run — the baseline the tests
+        compare the repair against).
         The handle's ``exact`` mode rides along unless the caller overrides
         it, so a hashed-id (``exact=False``) handle is reduced with the same
         id regime it was built and updated with.

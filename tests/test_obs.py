@@ -8,6 +8,7 @@ plan dumps the recorder next to the checkpoints; and the registry re-base
 of ``ServiceMetrics`` keeps ``summary()`` byte-compatible with the plain
 dict counters it replaced.
 """
+import asyncio
 import json
 import threading
 import tracemalloc
@@ -24,7 +25,9 @@ from repro.obs.registry import (
     MetricsRegistry,
     sanitize,
 )
-from repro.service import FaultPlan
+from repro.core.recovery import build_sharded, recover
+from repro.data import TabularStream
+from repro.service import FaultPlan, ReductServer, RetryPolicy
 from repro.service.metrics import RequestTiming, ServiceMetrics
 
 
@@ -237,6 +240,52 @@ def test_dump_gc_keeps_newest(tmp_path):
     left = sorted(f.name for f in tmp_path.glob("flightrec-*.json"))
     assert len(left) == trace_mod._MAX_DUMPS
     assert left[-1] == paths[-1].rsplit("/", 1)[1]  # newest survived
+
+
+def test_traced_chaos_run_covers_every_subsystem(tmp_path):
+    """A chaos run recorded end to end: a shard lost and re-folded, a
+    dispatch fault retried, a merge checkpointed.  The fault dumps the
+    flight recorder, and the exported trace is schema-valid and spans the
+    engine, scheduler, checkpoint and recovery subsystems."""
+    stream = TabularStream(n_rows=1200, n_attrs=8, v_max=3, n_dec=2,
+                           relevance=3, seed=5)
+    tracer = obs.enable()
+    ckdir = str(tmp_path)
+    plan = FaultPlan.parse("shard_drop@0:1,dispatch@0")
+    build = build_sharded(stream, 4, chunk_rows=256, fault_plan=plan)
+    assert build.lost == [1]
+    assert recover(build, stream) == [1]
+
+    async def drive():
+        async with ReductServer(checkpoint_dir=ckdir, fault_plan=plan,
+                                retry=RetryPolicy(base_delay_s=0.001),
+                                serve_stale=True) as srv:
+            x, d = stream.table()
+            half = len(x) // 2
+            await srv.submit("live", x[:half], d[:half],
+                             n_dec=stream.n_dec, v_max=stream.v_max)
+            await srv.query("live", "SCE")      # dispatch@0 fires
+            await srv.update("live", x[half:], d[half:])
+            await srv.query("live", "SCE")      # merge + checkpoint
+            return dict(srv.stats)
+
+    stats = asyncio.run(drive())
+    assert stats["retries"] >= 1, stats
+    assert stats["checkpoints"] >= 1, stats
+
+    dumps = list(tmp_path.glob("flightrec-*.json"))
+    assert dumps, "the fired fault dumped no flight recorder"
+    assert json.loads(dumps[0].read_text())["traceEvents"]
+
+    tracer.export(str(tmp_path / "chaos.json"))
+    doc = json.loads((tmp_path / "chaos.json").read_text())
+    events = doc["traceEvents"]
+    for ev in events:
+        assert {"name", "cat", "ph", "ts", "pid", "tid"} <= set(ev)
+        assert ev["ph"] in ("X", "i")
+        assert ev["ph"] != "X" or "dur" in ev
+    cats = {ev["cat"] for ev in events}
+    assert {"engine", "scheduler", "checkpoint", "recovery"} <= cats, cats
 
 
 # ---------------------------------------------------------------------------

@@ -412,6 +412,8 @@ def test_server_restart_restores_and_answers_warm(tmp_path):
     assert r2.reduct == r1.reduct
     assert r2.theta_history == r1.theta_history
     assert warm == 1  # first post-restart query repaired, not recomputed
+    # the restarted server's warm answer selects what a cold run selects
+    assert sorted(r2.reduct) == sorted(plar_reduce(x, d, delta="PR").reduct)
     assert r3.reduct  # post-restore update still serves
 
 
@@ -517,6 +519,35 @@ def test_serve_stale_degrades_to_last_good():
     assert degraded.stale
     assert degraded.reduct == good.reduct
     assert stats["stale_served"] == 1
+
+
+def test_fault_storm_is_absorbed(tmp_path):
+    """Transient dispatch faults, a merge fault and a checkpoint-write
+    crash across a stream of updates: every query is answered, no fault
+    reaches a client, and the retries and failed save are counted."""
+    rng = np.random.default_rng(17)
+    x = rng.integers(0, 3, (1200, 8)).astype(np.int32)
+    d = rng.integers(0, 2, (1200,)).astype(np.int32)
+    plan = FaultPlan.parse("dispatch@1,dispatch@3,merge@1,checkpoint@1")
+
+    async def drive():
+        async with ReductServer(
+                checkpoint_dir=str(tmp_path), fault_plan=plan,
+                retry=RetryPolicy(base_delay_s=0.001),
+                serve_stale=True) as srv:
+            await srv.submit("ds", x[:600], d[:600], n_dec=2, v_max=3)
+            answered = 0
+            for i in range(4):
+                lo = 600 + i * 150
+                await srv.update("ds", x[lo:lo + 150], d[lo:lo + 150])
+                r = await srv.query("ds", delta="PR")
+                answered += bool(r.reduct)
+            return answered, dict(srv.stats)
+
+    answered, stats = asyncio.run(drive())
+    assert answered == 4, "a fault leaked to a client"
+    assert len(plan.fired) >= 3, plan.fired
+    assert stats["retries"] >= 2
 
 
 def test_stopped_server_raises_typed_error():

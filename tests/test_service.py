@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import build_granularity, plar_reduce, with_capacity
+from repro.core.measures import f32_threshold
 from repro.data import scaled_paper_dataset
 from repro.service import (
     DatasetHandle,
@@ -163,6 +164,9 @@ def test_handle_reduce_warm_matches_cold(delta):
     cold = h.reduce(delta, warm=False)
     assert warm.reduct == cold.reduct
     assert warm.theta_history == cold.theta_history
+    # the repaired reduct is a super-reduct: it reaches the stopping target
+    target = f32_threshold(warm.theta_full, 1e-6) + 1e-6
+    assert warm.theta_history[-1] <= target
 
 
 # ---------------------------------------------------------------------------
